@@ -17,32 +17,25 @@ try:
 
     _jax.config.update("jax_enable_x64", True)
     # Persistent XLA compilation cache: the BLS kernels are large programs and
-    # this host compiles them slowly; warm runs (tests, benches, the chain)
-    # must not re-pay compilation. Opt out with LIGHTHOUSE_TPU_NO_JIT_CACHE=1.
+    # warm runs (tests, benches, the chain) must not re-pay compilation. JAX
+    # reads JAX_COMPILATION_CACHE_DIR itself; without it the cache sits at a
+    # fixed path in the checkout (the path is part of the key, so a moving
+    # directory never hits). Opt out with LIGHTHOUSE_TPU_NO_JIT_CACHE=1.
     if not _os.environ.get("LIGHTHOUSE_TPU_NO_JIT_CACHE"):
-        # Partition by host CPU fingerprint: the workspace survives across
-        # machines, and XLA:CPU AOT executables compiled for another host's
-        # feature set abort at run time (cpu_aot_loader SIGILL warning).
-        def _host_tag() -> str:
-            import hashlib as _hl
+        if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            # Partition by host CPU fingerprint: XLA:CPU AOT executables
+            # compiled for another host's feature set abort at run time
+            # (cpu_aot_loader SIGILL warning).
+            from .native.build import host_tag as _host_tag
 
-            try:
-                with open("/proc/cpuinfo") as _fh:
-                    for _line in _fh:
-                        if _line.startswith("flags"):
-                            return _hl.sha256(_line.encode()).hexdigest()[:12]
-            except OSError:
-                pass
-            import platform as _pl
-
-            return _hl.sha256(_pl.processor().encode()).hexdigest()[:12]
-
-        _cache_dir = _os.environ.get(
-            "LIGHTHOUSE_TPU_JIT_CACHE",
-            _os.path.join(_os.path.dirname(_os.path.abspath(__file__)),
-                          _os.pardir, ".jax_cache", _host_tag()),
-        )
-        _jax.config.update("jax_compilation_cache_dir", _cache_dir)
+            _jax.config.update(
+                "jax_compilation_cache_dir",
+                _os.path.join(
+                    _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+                    ".jax_cache",
+                    _host_tag(),
+                ),
+            )
         _jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
         _jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 except ImportError:  # the pure-Python oracle backend works without jax

@@ -1,8 +1,7 @@
 """Static-analysis subsystem: machine-checked kernel + concurrency
 certification.
 
-Six passes, run in tier-1 CI (``tests/test_analysis.py``), by the TPU
-window hunter's preflight (``tools_tpu_hunter.py``), and by hand via
+Six passes, run in tier-1 CI (``tests/test_analysis.py``) and by hand via
 ``python -m lighthouse_tpu.analysis``:
 
 * **Pass 1 — limb-bound certifier** (``bounds.py``): re-executes every
@@ -40,8 +39,8 @@ window hunter's preflight (``tools_tpu_hunter.py``), and by hand via
   the five device-resident subsystem plane families (epoch mirror,
   slasher spans, LC committee cache, KZG tables, firehose staging) as
   static ``*_bytes(config)`` functions parity-pinned against real
-  ``device_put`` accounting; and derives ``max_safe_shape(graph, tier)``
-  so the TPU window hunter skips unfittable rungs with a logged verdict.
+  ``device_put`` accounting; and derives ``max_safe_shape(graph, tier)``,
+  the largest certified batch per device tier.
   Emits ``MEMORY_CERT.json``; a row that fits no declared finite tier
   fails the certificate exactly like a tripped bound.
 """

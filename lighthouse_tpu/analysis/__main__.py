@@ -1,7 +1,7 @@
 """``python -m lighthouse_tpu.analysis`` — run the six-pass certifier suite.
 
 Exit code 0 iff every selected pass is clean. ``--json`` emits one machine-
-readable report on stdout (the hunter preflight consumes it); the default
+readable report on stdout; the default
 output is human-oriented. ``--bounds`` / ``--lint`` / ``--recompile`` /
 ``--supervisor`` / ``--concurrency`` / ``--memory`` select individual
 passes; with no selection all six run:
@@ -18,8 +18,7 @@ passes; with no selection all six run:
 6. **memory** — the device-memory certifier & static footprint planner
    (``MEMORY_CERT.json``): graph footprints under every conv backend x
    batch regime, pallas VMEM tile walk, the five subsystem residency
-   models, per-tier margins, and the ``max_safe_shape`` planner the
-   hunter's rung gate consumes.
+   models, per-tier margins, and the ``max_safe_shape`` planner.
 """
 
 from __future__ import annotations

@@ -7,7 +7,7 @@ callable identity), so it may add exactly ZERO steady-state recompiles —
 one stray recompile per supervised call is the hazard the recompilation
 sentinel exists to catch, multiplied across the whole hot path.
 
-This pass proves three properties, cheaply enough for the hunter preflight:
+This pass proves three properties, cheaply enough for every CLI run:
 
 1. the ``resilience`` package itself lints clean under the trace-hygiene
    rules (its jit-facing wrappers introduce no host-sync/tracer-branch

@@ -31,27 +31,6 @@ from ..ops.bls_oracle import curves as _oc
 RAND_BITS = 64  # blst.rs:16
 
 
-def _shard_map():
-    """shard_map across jax versions: top-level (newer jax exports
-    ``jax.shard_map``) with the experimental namespace as the fallback —
-    older builds raise ImportError from ``from jax import shard_map`` and
-    used to FAIL the sharded tests instead of running them. Those older
-    builds also lack a replication rule for ``while`` (the Miller loop's
-    fori/scan), so the wrapper passes ``check_rep=False`` where the kwarg
-    exists (its documented workaround) and drops it where it doesn't."""
-    try:
-        from jax import shard_map as sm
-    except ImportError:  # pragma: no cover - version-dependent
-        from jax.experimental.shard_map import shard_map as sm
-
-    def wrapped(f, **kw):
-        try:
-            return sm(f, check_rep=False, **kw)
-        except TypeError:  # pragma: no cover - newer jax: kwarg removed
-            return sm(f, **kw)
-
-    return wrapped
-
 _MINUS_G1 = _oc.g1_neg(_oc.g1_generator())
 _MG1_X = fq.from_int(_MINUS_G1[0])
 _MG1_Y = fq.from_int(_MINUS_G1[1])
@@ -270,13 +249,16 @@ def _gathered_kernel(n_pad: int, k_pad: int):
     return run
 
 
-def stage_lowerings(n_pad: int, k_pad: int, n_validators: int = 1024):
+def stage_lowerings(
+    n_pad: int, k_pad: int, n_validators: int = 1024, sharding=None
+):
     """(name, jax Lowered) for each device stage of the gathered chain-hot-path
     kernel at the given shapes — shared by the compile probes and the bench's
     cost analysis (the staged design means there is no single fused program
-    to introspect)."""
+    to introspect). ``sharding`` places every argument, e.g. on a described
+    TPU device, to compile for a chip that is not attached."""
     u64 = jnp.uint64
-    sd = jax.ShapeDtypeStruct
+    sd = functools.partial(jax.ShapeDtypeStruct, sharding=sharding)
     u = sd((n_pad, 2, 25), u64)
     return [
         ("h2c", _h2c_stage(n_pad).lower(u, u)),
@@ -305,6 +287,52 @@ def stage_lowerings(n_pad: int, k_pad: int, n_validators: int = 1024):
                 u,
                 sd((n_pad,), jnp.bool_),
                 sd((n_pad,), jnp.bool_),
+            ),
+        ),
+    ]
+
+
+def sharded_stage_lowerings(mesh, n_pad: int, k_pad: int, n_validators: int):
+    """(name, jax Lowered) for each stage of the per-shard-verdict serving
+    path (``verify_staged_pershard``) on ``mesh``: per-set arrays sharded
+    over ``sets``, the pubkey cache replicated. ``mesh`` may be built from
+    described devices, to compile for chips that are not attached."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    sets = NamedSharding(mesh, P("sets"))
+    n_dev = mesh.devices.size
+
+    def sd(shape, dtype=jnp.uint64, sharding=sets):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    u = sd((n_pad, 2, 25))
+    b = sd((n_pad,), jnp.bool_)
+    return [
+        ("h2c", _sharded_h2c_stage(mesh, n_pad).lower(u, u)),
+        (
+            "prep",
+            _sharded_prep_stage(mesh, n_pad, k_pad).lower(
+                sd((n_validators, 3, 25), sharding=NamedSharding(mesh, P())),
+                sd((n_pad, k_pad), jnp.int32),
+                sd((n_pad, k_pad), jnp.bool_),
+                sd((n_pad, 25)),
+                sd((n_pad, 25)),
+                sd((n_pad,)),
+                b,
+                sd((n_pad,)),
+                b,
+            ),
+        ),
+        (
+            "verdict",
+            _sharded_verdict_stage(mesh, n_pad).lower(
+                sd((n_pad, 1, 25)),
+                sd((n_pad, 1, 25)),
+                u,
+                u,
+                sd((n_dev, 6, 25)),
+                sd((n_dev,), jnp.bool_),
+                b,
             ),
         ),
     ]
@@ -376,7 +404,6 @@ def verify_indexed_sets_device(cache_arr, items) -> bool:
 def _sharded_h2c_stage(mesh, n_pad: int):
     """Sharded twin of ``_h2c_stage``: SSWU/isogeny/cofactor/affine on each
     device's local slice of the sets axis (purely local — no collectives)."""
-    shard_map = _shard_map()
     from jax.sharding import PartitionSpec as P
 
     from ..ops.bls import h2c
@@ -384,9 +411,10 @@ def _sharded_h2c_stage(mesh, n_pad: int):
     def local(u0, u1):
         return g2.to_affine(h2c.map_to_g2(u0, u1))
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh, in_specs=(P("sets"),) * 2,
         out_specs=(P("sets"),) * 2,
+        check_vma=False,
     ))
 
 
@@ -418,20 +446,19 @@ def _sharded_prep_stage(mesh, n_pad: int, k_pad: int):
     parity; ~100 MB at 1M validators, well within HBM); each device
     decompresses, gathers, and aggregates only its n/n_dev sets and emits
     per-device G2 signature partial sums + a per-device set_ok verdict."""
-    shard_map = _shard_map()
     from jax.sharding import PartitionSpec as P
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         _local_prep_partials, mesh=mesh,
         in_specs=(P(),) + (P("sets"),) * 8,
         out_specs=(P("sets"),) * 4,
+        check_vma=False,
     ))
 
 
 @functools.lru_cache(maxsize=None)
 def _sharded_array_prologue_stage(mesh, n_pad: int):
     """Sharded twin of ``_prologue_stage`` (pre-aggregated pk/sig arrays)."""
-    shard_map = _shard_map()
     from jax.sharding import PartitionSpec as P
 
     def local(pk_agg, sig, scalars, valid):
@@ -439,9 +466,10 @@ def _sharded_array_prologue_stage(mesh, n_pad: int):
         pkx, pky = g1.to_affine(pk_scaled)
         return pkx, pky, sig_part[None], jnp.all(set_ok)[None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh, in_specs=(P("sets"),) * 4,
         out_specs=(P("sets"),) * 4,
+        check_vma=False,
     ))
 
 
@@ -449,7 +477,6 @@ def _sharded_array_prologue_stage(mesh, n_pad: int):
 def _sharded_miller_stage(mesh, n_pad: int):
     """Per-device Miller loops over the local sets plus the local Fq12
     product — one [n_dev, 12, 25] partial per device."""
-    shard_map = _shard_map()
     from jax.sharding import PartitionSpec as P
 
     def local(pkx, pky, mxa, mya, valid):
@@ -461,9 +488,10 @@ def _sharded_miller_stage(mesh, n_pad: int):
         )
         return f[None], jnp.any(valid)[None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh, in_specs=(P("sets"),) * 5,
         out_specs=(P("sets"),) * 2,
+        check_vma=False,
     ))
 
 
@@ -599,7 +627,6 @@ def _sharded_verdict_stage(mesh, n_pad: int):
     on its own slice and emits ONE bool — the gathered [n_dev] output is the
     per-shard verdict vector (the cross-device combine of the serving tier:
     an output gather, no arithmetic collectives)."""
-    shard_map = _shard_map()
     from jax.sharding import PartitionSpec as P
 
     def local(pkx, pky, mxa, mya, sig_part, ok_part, valid):
@@ -607,8 +634,9 @@ def _sharded_verdict_stage(mesh, n_pad: int):
             pkx, pky, mxa, mya, sig_part[0], ok_part[0], valid
         )[None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh, in_specs=(P("sets"),) * 7, out_specs=P("sets"),
+        check_vma=False,
     ))
 
 

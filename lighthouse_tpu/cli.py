@@ -76,8 +76,8 @@ def _add_spec_flags(p):
     )
     p.add_argument(
         "--platform", default="auto", choices=("auto", "cpu", "tpu"),
-        help="JAX platform: 'cpu' forces host execution even where an "
-             "accelerator plugin force-selects itself (the devcpu.py recipe)",
+        help="JAX platform: 'cpu' forces host execution on a machine with "
+             "an accelerator (the devcpu.py recipe)",
     )
     for fork in ("altair", "bellatrix", "capella", "deneb", "electra"):
         p.add_argument(f"--{fork}-fork-epoch", type=int, default=None)

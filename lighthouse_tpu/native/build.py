@@ -1,20 +1,37 @@
 """Build + load the native BLS12-381 shared library.
 
-Compiles lighthouse_tpu/native/bls12_381.cpp with g++ -O3 into
-``_build/libbls12_381.so`` (cached; rebuilt when the source is newer) and
-returns a configured ctypes handle.
+Compiles lighthouse_tpu/native/bls12_381.cpp with g++ -O3 -march=native
+into ``_build/<host tag>/libbls12_381.so`` (cached; rebuilt when the source
+is newer) and returns a configured ctypes handle. The host tag fingerprints
+the CPU's feature flags: a library built for one host's instruction set
+dies with SIGILL on another.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 
+
+def host_tag() -> str:
+    """12-hex fingerprint of this host's CPU feature flags."""
+    try:
+        with open("/proc/cpuinfo") as fh:
+            for line in fh:
+                if line.startswith("flags"):
+                    return hashlib.sha256(line.encode()).hexdigest()[:12]
+    except OSError:
+        pass
+    return hashlib.sha256(platform.processor().encode()).hexdigest()[:12]
+
+
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "bls12_381.cpp")
-_BUILD_DIR = os.path.join(_DIR, "_build")
+_BUILD_DIR = os.path.join(_DIR, "_build", host_tag())
 _LIB = os.path.join(_BUILD_DIR, "libbls12_381.so")
 
 _lock = threading.Lock()

@@ -372,7 +372,7 @@ _CONV_IMPL = None
 def conv_backend() -> str:
     """Which conv implementation the default backend gets: "pallas" on TPU
     (fused Pallas/Mosaic digit kernels — conv + congruence fold + carry as
-    one MXU kernel, see pallas_kernels.py), "f64" elsewhere (CPU SIMD FMA).
+    one kernel, see pallas_kernels.py), "f64" elsewhere (CPU SIMD FMA).
     Cached on first use; override via
     LIGHTHOUSE_CONV_IMPL=pallas|digits|f64|shear for testing ("pallas" off
     TPU runs the kernels in interpret mode — exact, but an emulator)."""

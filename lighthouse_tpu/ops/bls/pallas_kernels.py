@@ -1,4 +1,4 @@
-"""Fused Pallas/Mosaic limb kernels: conv -> congruence-fold -> carry on the MXU.
+"""Fused Pallas/Mosaic limb kernels: conv -> congruence-fold -> carry in one kernel.
 
 The third conv backend (``LIGHTHOUSE_CONV_IMPL=pallas``, the TPU default).
 The u64/f64 backends materialize the limb multiply pipeline as separate HLO
@@ -8,33 +8,33 @@ memory per call. Here the WHOLE pipeline after the input lincombs runs as ONE
 ``pl.pallas_call`` per tower op:
 
 * **Number format.** Everything inside the kernel is base-2^8 *digit planes*
-  in f32 (Mosaic has no u64; f32 FMA is the full-rate VPU/MXU path — the same
-  reasoning as ``fq._conv_product_digits``). A 25x16-bit-limb element is 51
+  in f32 (Mosaic has no u64 — the same reasoning as
+  ``fq._conv_product_digits``). A 25x16-bit-limb element is 51
   digits; digit bounds are tracked exactly (Python ints) and every
   intermediate is proven < 2^24, the f32 integer-exactness cap, so the whole
   kernel is EXACT integer arithmetic in float registers.
 
-* **Convolution as an MXU matmul tile.** The 51x51 digit outer product is
-  flattened and multiplied by a constant 0/1 *shear* matrix S[(i,j), i+j]
-  ([2601, 101]): one ``dot_general`` against constant weights — the systolic
-  array does the anti-diagonal accumulation that the unrolled shifted-FMA
-  chain of the XLA digits backend spreads over 51 VPU passes.
+* **Lane planes.** Every in-kernel array is a 2-D ``[8, 128]`` f32 plane —
+  one vreg: digit position d of a row sits in lane d. Lanes of a tower op
+  ride in a leading axis (``[lanes, rows, 128]`` blocks) and the body walks
+  the block 8 rows at a time, so Mosaic sees only lane rotations, lane
+  broadcasts and leading-axis ref indexing (its layout inference refuses,
+  e.g., a 4-D digit outer product reshaped to 2-D).
 
-* **Congruence fold as a matmul.** Digit positions >= 48 (weight 2^384) fold
-  through constant rows F8[h] = digits(2^(8*(48+h)) mod p): a
-  ``[batch, n_hi] x [n_hi, 48]`` dot — exactly the shape the PR-4 f64 matmul
-  fold wanted, now on MXU tiles inside the kernel.
+* **Convolution as a shifted-FMA chain.** ``t += A[:, i] * roll(B, i)`` for
+  the 51 digits of A: a lane broadcast, a lane rotation and an FMA per step
+  on the VPU. The 101 conv positions stay below lane 128, so no rotation
+  wraps a non-zero digit.
 
-* **Carry rounds stay in-register.** The width-preserving base-2^8
-  carry-save rounds (exact f32 floor-multiply splits) interleave with folds
-  per a STATIC schedule derived from the exact bound walk — the in-kernel
-  twin of ``fq.reduce_limbs``'s phase structure, with zero HLO round-trips.
+* **Congruence fold.** Digit positions >= 48 (weight 2^384) fold through
+  constant rows F8[h] = digits(2^(8*(48+h)) mod p) as broadcast-FMA terms.
 
 * **The out-lincomb rides inside too** (``execute_plan``): a tower op's
   output linear map runs on the unreduced conv digits as one
-  ``[R, L] x [tile, L, W]`` contraction (negative coefficients via
-  digit-space borrow constants == 0 mod p), so an fq12 multiply still reduces
-  12 rows, not 54 lanes — the plans.py contract, fused.
+  sum of lane planes with the integer coefficients unrolled into the body
+  (negative coefficients via digit-space borrow constants == 0 mod p), so an
+  fq12 multiply still reduces 12 rows, not 54 lanes — the plans.py
+  contract, fused.
 
 Every bound the schedule relies on is recorded as a trace-time ``fq._cert``
 obligation (kinds ``pallas_*``) and proven per-graph by
@@ -58,6 +58,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import fq
 from ..bls_oracle.fields import P
@@ -68,33 +69,28 @@ _FOLD_BASE = 48         # digit position of 2^384: everything above folds mod p
 _F32_CAP = (1 << 24) - 1  # f32 integer exactness cap
 _N_FOLD8 = 64           # fold rows provisioned (widths stay far below this)
 
-_LIMB_PER = 2           # digits per 16-bit limb
 _OUT_D = 50             # output digit positions (25 limbs)
+_W = 128                # lanes of an in-kernel digit plane
 
 
 def _int_to_digits(x: int, n: int) -> list[int]:
     return [(x >> (8 * i)) & 0xFF for i in range(n)]
 
 
-# Constant shear: S[(i, j), i + j] = 1 — conv as one MXU matmul.
-_SHEAR_NP = np.zeros((_D * _D, _CONV_D), dtype=np.float32)
-for _i in range(_D):
-    for _j in range(_D):
-        _SHEAR_NP[_i * _D + _j, _i + _j] = 1.0
-
 # Congruence-fold rows in digit space: F8[h] = digits48(2^(8*(48+h)) mod p).
-# Residues are < p < 2^381 — 48 digits each, entries <= 255.
+# Residues are < p < 2^381 — 48 digits each, entries <= 255; the rows are
+# 128-lane planes whose lanes >= 48 hold zeros.
 _FOLD8_NP = np.stack(
     [
         np.array(
-            _int_to_digits((1 << (8 * (_FOLD_BASE + h))) % P, _FOLD_BASE),
+            _int_to_digits((1 << (8 * (_FOLD_BASE + h))) % P, _W),
             dtype=np.float32,
         )
         for h in range(_N_FOLD8)
     ]
 )
 _FOLD8_INT = [
-    [int(v) for v in _FOLD8_NP[h]] for h in range(_N_FOLD8)
+    [int(v) for v in _FOLD8_NP[h, :_FOLD_BASE]] for h in range(_N_FOLD8)
 ]
 _FOLD8_VALS = [(1 << (8 * (_FOLD_BASE + h))) % P for h in range(_N_FOLD8)]
 
@@ -118,23 +114,20 @@ _VMEM_SINK: list | None = None
 def _record_vmem(tile: int, L: int, rows_p: int, out_key) -> None:
     if _VMEM_SINK is None:
         return
+    plane = tile * _W * 4  # one [tile, 128] f32 plane
     if out_key is not None:
-        R, mpos_np, mneg_np, oconst_np, n_pass, pass_w = _OUT_TABLE[out_key]
+        R, _, mneg_np, _, n_pass = _OUT_TABLE[out_key]
         n_rows_out = R
-        const_b = mpos_np.nbytes
-        if bool(mneg_np.any()):
-            const_b += mneg_np.nbytes + oconst_np.nbytes
+        const_b = R * _W * 4 if bool(mneg_np.any()) else 0
     else:
-        n_rows_out, n_pass, pass_w = L, 0, 0
+        n_rows_out, n_pass = L, 0
         const_b = 0
-    const_b += _SHEAR_NP.nbytes + _FOLD8_NP.nbytes
-    blocks_in = 2 * tile * L * _D * 4
-    if n_pass:
-        blocks_in += tile * n_pass * pass_w * 4
-    block_out = tile * n_rows_out * _OUT_D * 4
-    # the in-kernel digit outer product dominates (_row_tile budgets ~4 MiB
-    # for it); grid-blocked operands double-buffer across grid steps
-    prod = tile * L * _D * _D * 4
+    const_b += _FOLD8_NP.nbytes
+    blocks_in = (2 * L + n_pass) * plane
+    block_out = n_rows_out * plane
+    # the body works one 8-row chunk at a time; the lane results of a
+    # chunk wait in a [L, 8, 128] scratch for the out-lincomb
+    work = L * _CH * _W * 4
     _VMEM_SINK.append({
         "tile": tile,
         "lanes": L,
@@ -143,8 +136,9 @@ def _record_vmem(tile: int, L: int, rows_p: int, out_key) -> None:
         "n_pass": n_pass,
         "block_bytes": blocks_in + block_out,
         "const_bytes": const_b,
-        "outer_product_bytes": prod,
-        "est_vmem_bytes": prod + 2 * (blocks_in + block_out) + const_b,
+        "scratch_bytes": work,
+        # grid-blocked operands double-buffer across grid steps
+        "est_vmem_bytes": work + 2 * (blocks_in + block_out) + const_b,
     })
 
 
@@ -226,12 +220,6 @@ def _reduce_schedule(
     row term, so folding past the value target would chase its own tail."""
     ops: list = []
 
-    def trim(s: _DState) -> _DState:
-        t = _trim_state(s)
-        if len(t.digits) != len(s.digits):
-            ops.append(("trim", len(t.digits)))
-        return t
-
     def limbs_fit(s: _DState) -> bool:
         if len(s.digits) > _OUT_D:
             return False
@@ -242,8 +230,11 @@ def _reduce_schedule(
         )
 
     for _ in range(96):
-        s = trim(s)
+        # dropping zero-bound top positions needs no kernel op: those lanes
+        # already hold zeros
+        s = _trim_state(s)
         w = len(s.digits)
+        _lane_cert(w, name)
         if w > _OUT_D or (s.value > value_limit and w > _FOLD_BASE):
             if _fold_budget(s) <= _F32_CAP:
                 s = _fold_state(s, name)
@@ -332,54 +323,74 @@ def _pow2_floor(n: int) -> int:
     return p
 
 
+# The kernel body works on one 8-row chunk ([8, 128] f32 — one vreg per
+# plane) at a time; a grid step's block holds `tile` rows of every lane,
+# with tile * lanes <= _TILE_ROWS (~0.5 MiB per operand block).
+_CH = 8
+_TILE_ROWS = 1024
+
+
 def _row_tile(rows: int, lanes: int) -> int:
-    """Row-tile size: the in-kernel outer product is [tile, L, 51, 51] f32 —
-    budget ~4 MiB of VMEM for it (grid steps pipeline the rest)."""
-    budget = (4 << 20) // max(1, lanes * _D * _D * 4)
-    tile = max(8, min(128, _pow2_floor(max(1, budget))))
-    return min(tile, max(8, _pow2_floor(max(1, rows))))
+    """Row-tile size (a multiple of the 8-row chunk)."""
+    tile = max(_CH, min(512, _pow2_floor(max(1, _TILE_ROWS // max(1, lanes)))))
+    return min(tile, max(_CH, -(-rows // _CH) * _CH))
+
+
+def _lane_cert(width: int, name: str) -> None:
+    """A plane of ``width`` digit positions must fit the 128-lane register
+    row with its top lane free: a carry-save split rolls every lane up one,
+    and lane 127 wraps to lane 0."""
+    assert fq._cert(
+        "pallas_lane_width", width, _W, note=name
+    ), f"{name}: pallas digit plane exceeds {_W} lanes"
+
+
+def _roll(t, k: int):
+    """Rotate the lanes of t up by k."""
+    return pltpu.roll(t, k, 1)
+
+
+def _bcast_lane(t, i: int):
+    """Column ``i`` of t broadcast across the lanes."""
+    return jnp.broadcast_to(t[:, i : i + 1], t.shape)
+
+
+def _conv(A, B):
+    """Digit convolution on [rows, 128] planes: sum_i A[:, i] * (B << i
+    lanes). Operand digits live in lanes 0..50 and the 101 output positions
+    stay below lane 128, so the lane rotation never wraps a non-zero digit.
+    Exact: every product and partial sum is an integer < 2^24 (certified as
+    pallas_conv_digit_f32_exact)."""
+    t = _bcast_lane(A, 0) * B
+    for i in range(1, _D):
+        t = t + _bcast_lane(A, i) * _roll(B, i)
+    return t
 
 
 def _split_array(t):
     """In-kernel base-2^8 carry-save round (exact: digits < 2^24)."""
     hi = jnp.floor(t * (1.0 / 256.0))
-    lo = t - hi * 256.0
-    nb = [(0, 0)] * (t.ndim - 1)
-    return jnp.pad(lo, nb + [(0, 1)]) + jnp.pad(hi, nb + [(1, 0)])
+    return (t - hi * 256.0) + _roll(hi, 1)
 
 
-# Every in-kernel contraction is integer arithmetic in f32 registers: the
-# MXU must NOT lower it through reduced-precision bf16 passes (the default
-# f32 matmul policy on TPU), or the certified < 2^24 exactness silently
-# breaks on the first real window. HIGHEST forces true f32 accumulation;
-# on the CPU interpreter it is a no-op.
-_EXACT = jax.lax.Precision.HIGHEST
+def _fold_array(t, n_hi: int, f8_ref):
+    """Fold digit positions 48..48+n_hi through the 2^(8k) mod p rows:
+    broadcast-FMA terms on the VPU, exact like the conv (every product is
+    bounded by the certified post-fold digit, < 2^24)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, t.shape, 1)
+    acc = jnp.where(lane < _FOLD_BASE, t, 0.0)
+    for h in range(n_hi):
+        acc = acc + _bcast_lane(t, _FOLD_BASE + h) * f8_ref[h : h + 1, :]
+    return acc
 
 
-def _replay(t, ops, f8):
+def _replay(t, ops, f8_ref):
     """Apply a static reduce schedule to in-kernel digit planes."""
     for op in ops:
         if op[0] == "split":
             t = _split_array(t)
-        elif op[0] == "trim":
-            t = t[..., : op[1]]
-        else:  # fold
-            n_hi = op[1]
-            hi = t[..., _FOLD_BASE:]
-            folded = jax.lax.dot_general(
-                hi,
-                f8[:n_hi],
-                (((t.ndim - 1,), (0,)), ((), ())),
-                precision=_EXACT,
-                preferred_element_type=jnp.float32,
-            )
-            t = t[..., :_FOLD_BASE] + folded
-    return t
-
-
-def _pad_width(t, w: int):
-    if t.shape[-1] < w:
-        t = jnp.pad(t, [(0, 0)] * (t.ndim - 1) + [(0, w - t.shape[-1])])
+        else:
+            t = _fold_array(t, op[1], f8_ref)
     return t
 
 
@@ -389,119 +400,105 @@ def _build_call(
     tile: int,
     n_lanes: int,
     pre_ops: tuple,
-    out_key,          # None | (R, mpos bytes-key, mneg key, oconst key, n_pass, pass_w)
+    out_key,          # None | (R, mpos bytes-key, mneg key, oconst key, n_pass)
     post_ops: tuple,
     interpret: bool,
 ):
     """Build (and cache) the fused pallas_call for one static signature.
-    The matrices referenced by ``out_key`` are re-materialized from the
-    per-key side table (they are part of the cache key via content hash)."""
+
+    Operands are digit planes ``[lanes, rows, 128]`` f32; a grid step takes
+    ``tile`` rows of every lane. The out-lincomb's integer coefficients are
+    unrolled into the kernel body (they are part of the cache key via
+    ``out_key``); only its borrow constants ride in as an operand."""
     L = n_lanes
     grid = rows_p // tile
     has_out = out_key is not None
     if has_out:
-        R, mpos_np, mneg_np, oconst_np, n_pass, pass_w = _OUT_TABLE[out_key]
+        R, mpos_np, mneg_np, oconst_np, n_pass = _OUT_TABLE[out_key]
         has_neg = bool(mneg_np.any())
         n_rows_out = R
+        terms = [
+            (
+                [(i, float(c)) for i, c in enumerate(mpos_np[r]) if c],
+                [(i, float(c)) for i, c in enumerate(mneg_np[r]) if c],
+            )
+            for r in range(R)
+        ]
     else:
         n_rows_out = L
         has_neg = False
         n_pass = 0
 
-    def body(*refs):
-        a_ref, b_ref, shear_ref, f8_ref = refs[:4]
-        idx = 4
-        if has_out:
-            mpos_ref = refs[idx]
-            idx += 1
-            if has_neg:
-                mneg_ref, oconst_ref = refs[idx : idx + 2]
-                idx += 2
-        if n_pass:
-            ain_ref = refs[idx]
-            idx += 1
-        o_ref = refs[idx]
-        A = a_ref[...]  # [tile, L, 51]
-        B = b_ref[...]
-        # conv: digit outer product, anti-diagonals summed by the constant
-        # shear matmul — one MXU tile per (row, lane)
-        prod = A[..., :, None] * B[..., None, :]  # [tile, L, 51, 51]
-        flat = prod.reshape(tile * L, _D * _D)
-        t = jax.lax.dot_general(
-            flat,
-            shear_ref[...],
-            (((1,), (0,)), ((), ())),
-            precision=_EXACT,
-            preferred_element_type=jnp.float32,
-        )
-        t = t.reshape(tile, L, _CONV_D)
-        t = _replay(t, pre_ops, f8_ref[...])
-        if has_out:
-            w = t.shape[-1]
-            if n_pass:
-                t = jnp.concatenate(
-                    [t, _pad_width(ain_ref[...], w)], axis=-2
-                )
-            pos = jnp.einsum(
-                "tld,rl->trd", t, mpos_ref[...],
-                precision=_EXACT,
-                preferred_element_type=jnp.float32,
-            )
-            if has_neg:
-                neg = jnp.einsum(
-                    "tld,rl->trd", t, mneg_ref[...],
-                    precision=_EXACT,
-                    preferred_element_type=jnp.float32,
-                )
-                t = pos + (oconst_ref[...][None, :, :] - neg)
-            else:
-                t = pos
-        t = _replay(t, post_ops, f8_ref[...])
-        o_ref[...] = _pad_width(t, _OUT_D)
+    def scaled(x, c):
+        return x if c == 1.0 else x * c
 
-    # assemble specs
-    def bs(shape):
-        n = len(shape)
-        return pl.BlockSpec(
-            (tile,) + shape, lambda i, _n=n: (i,) + (0,) * _n
-        )
+    def body(*refs):
+        a_ref, b_ref, f8_ref = refs[:3]
+        rest = list(refs[3:])
+        oconst_ref = rest.pop(0) if has_neg else None
+        ain_ref = rest.pop(0) if n_pass else None
+        o_ref, lanes_ref = rest
+
+        def chunk(c, carry):
+            rs = pl.ds(pl.multiple_of(c * _CH, _CH), _CH)
+
+            def lane(i, carry):
+                t = _replay(_conv(a_ref[i, rs, :], b_ref[i, rs, :]), pre_ops, f8_ref)
+                if has_out:
+                    lanes_ref[i] = t  # lint: allow(impure-closure) — ref store
+                else:
+                    o_ref[i, rs, :] = t  # lint: allow(impure-closure) — ref store
+                return carry
+
+            jax.lax.fori_loop(0, L, lane, 0)
+            if not has_out:
+                return carry
+            src = [lanes_ref[i] for i in range(L)]
+            src += [ain_ref[j, rs, :] for j in range(n_pass)]
+            for r, (pos, neg) in enumerate(terms):
+                acc = jnp.zeros((_CH, _W), jnp.float32)
+                for i, c in pos:
+                    acc = acc + scaled(src[i], c)
+                if neg:
+                    # C_r - sum(neg) stays in [0, C_r]: C_r covers the
+                    # negative part digit by digit
+                    cov = jnp.broadcast_to(oconst_ref[r : r + 1, :], (_CH, _W))
+                    for i, c in neg:
+                        cov = cov - scaled(src[i], c)
+                    acc = acc + cov
+                o_ref[r, rs, :] = _replay(acc, post_ops, f8_ref)  # lint: allow(impure-closure) — ref store
+            return carry
+
+        jax.lax.fori_loop(0, tile // _CH, chunk, 0)
+
+    def bs(n):
+        return pl.BlockSpec((n, tile, _W), lambda i: (0, i, 0))
 
     def const_bs(shape):
-        n = len(shape)
-        return pl.BlockSpec(shape, lambda i, _n=n: (0,) * _n)
+        return pl.BlockSpec(shape, lambda i, _n=len(shape): (0,) * _n)
 
-    in_specs = [
-        bs((L, _D)),
-        bs((L, _D)),
-        const_bs(_SHEAR_NP.shape),
-        const_bs(_FOLD8_NP.shape),
-    ]
+    in_specs = [bs(L), bs(L), const_bs(_FOLD8_NP.shape)]
     # keep the constant operands as NUMPY in the cached closure: a jnp
     # constant materialized inside whatever trace first built this call
     # would be a trace-local tracer — caching it leaks it into every later
     # trace (UnexpectedTracerError). asarray at run time is a per-trace
     # constant, folded by XLA.
-    operands_const = [_SHEAR_NP, _FOLD8_NP]
-    if has_out:
-        in_specs.append(const_bs(mpos_np.shape))
-        operands_const.append(mpos_np)
-        if has_neg:
-            in_specs += [const_bs(mneg_np.shape), const_bs(oconst_np.shape)]
-            operands_const += [mneg_np, oconst_np]
+    operands_const = [_FOLD8_NP]
+    if has_neg:
+        oconst_w = np.zeros((R, _W), dtype=np.float32)
+        oconst_w[:, : oconst_np.shape[1]] = oconst_np
+        in_specs.append(const_bs(oconst_w.shape))
+        operands_const.append(oconst_w)
     if n_pass:
-        in_specs.append(bs((n_pass, pass_w)))
-    out_spec = pl.BlockSpec(
-        (tile, n_rows_out, _OUT_D), lambda i: (i, 0, 0)
-    )
+        in_specs.append(bs(n_pass))
 
     call = pl.pallas_call(
         body,
         grid=(grid,),
         in_specs=in_specs,
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct(
-            (rows_p, n_rows_out, _OUT_D), jnp.float32
-        ),
+        out_specs=bs(n_rows_out),
+        out_shape=jax.ShapeDtypeStruct((n_rows_out, rows_p, _W), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((L, _CH, _W), jnp.float32)],
         interpret=interpret,
     )
 
@@ -509,7 +506,10 @@ def _build_call(
         args = [A_d, B_d] + [jnp.asarray(c) for c in operands_const]
         if n_pass:
             args.append(Ain_d)
-        return call(*args)
+        # the kernel is all f32/i32: traced under x64, its indices, loop
+        # counters and shifts would be i64, which Mosaic does not lower
+        with jax.enable_x64(False):
+            return call(*args)
 
     return run
 
@@ -520,16 +520,9 @@ def _build_call(
 _OUT_TABLE: dict = {}
 
 
-def _out_key(R, mpos, mneg, oconst, n_pass, pass_w):
-    key = (
-        R,
-        mpos.tobytes(),
-        mneg.tobytes(),
-        oconst.tobytes(),
-        n_pass,
-        pass_w,
-    )
-    _OUT_TABLE[key] = (R, mpos, mneg, oconst, n_pass, pass_w)
+def _out_key(R, mpos, mneg, oconst, n_pass):
+    key = (R, mpos.tobytes(), mneg.tobytes(), oconst.tobytes(), n_pass)
+    _OUT_TABLE[key] = (R, mpos, mneg, oconst, n_pass)
     return key
 
 
@@ -538,13 +531,12 @@ def _out_key(R, mpos, mneg, oconst, n_pass, pass_w):
 # --------------------------------------------------------------------------------------
 
 
-def _digits_of(x):
-    """u64 limb planes -> f32 digit planes (outside the kernel: Mosaic has
-    no u64; the extraction is a handful of fused elementwise HLO ops)."""
-    if x.dtype != jnp.uint64:
-        # the f64 walk never reaches the pallas path; accept exact-int casts
-        x = x.astype(jnp.uint64)
-    return fq._to_digits_f32(x)
+def _planes(x, n_lanes: int, rows_p: int):
+    """u64 limbs [..., n_lanes, 25] -> f32 digit planes [n_lanes, rows_p, 128]
+    (digit extraction stays in XLA: Mosaic has no u64)."""
+    d = fq._to_digits_f32(x.astype(jnp.uint64)).reshape(-1, n_lanes, _D)
+    d = jnp.moveaxis(d, 1, 0)
+    return jnp.pad(d, ((0, 0), (0, rows_p - d.shape[1]), (0, _W - _D)))
 
 
 def _limbs_of(d):
@@ -562,33 +554,26 @@ def _rows_of(shape) -> int:
     return n
 
 
-def _run_fused(A_d, B_d, pre_ops, out_key, post_ops, Ain_d=None):
-    """Pad rows to the tile multiple, run the cached call, slice back."""
-    rows = A_d.shape[0]
-    L = A_d.shape[1]
+def _run_fused(A, B, pre_ops, out_key, post_ops, Ain=None):
+    """Run the cached call on u64 operands [rows, L, 25] (Ain: [rows,
+    n_pass, 25]); returns limbs [rows, n_rows_out, 25]."""
+    rows, L = A.shape[0], A.shape[1]
     tile = _row_tile(rows, L)
     rows_p = -(-rows // tile) * tile
-    pad = [(0, rows_p - rows)] + [(0, 0)] * (A_d.ndim - 1)
-    if rows_p != rows:
-        A_d = jnp.pad(A_d, pad)
-        B_d = jnp.pad(B_d, pad)
-        if Ain_d is not None:
-            Ain_d = jnp.pad(
-                Ain_d, [(0, rows_p - rows)] + [(0, 0)] * (Ain_d.ndim - 1)
-            )
     _record_vmem(tile, L, rows_p, out_key)
     run = _build_call(
         rows_p, tile, L, tuple(pre_ops), out_key, tuple(post_ops), _interpret()
     )
-    out = run(A_d, B_d, Ain_d)
-    return out[:rows]
+    ain = None if Ain is None else _planes(Ain, Ain.shape[1], rows_p)
+    out = run(_planes(A, L, rows_p), _planes(B, L, rows_p), ain)
+    return _limbs_of(jnp.moveaxis(out[:, :rows, :_OUT_D], 0, 1))
 
 
 def fused_mul(a, b, lazy: bool = False):
     """The fused pallas twin of fq.mont_mul (lazy=False: operands within the
     lazy budget, output at plans.PUB_BOUND) / fq.mont_mul_lazy (lazy=True:
     chain-bound operands and output — the chain fixed point). One pallas_call:
-    digit conv (MXU shear matmul) -> static fold/carry schedule, all
+    digit conv (VPU shifted-FMA chain) -> static fold/carry schedule, all
     in-register."""
     name = "pallas_mul_lazy" if lazy else "pallas_mul"
     if lazy:
@@ -600,8 +585,6 @@ def fused_mul(a, b, lazy: bool = False):
     a, b = jnp.broadcast_arrays(a, b)
     batch = a.shape[:-1]
     rows = _rows_of(batch)
-    da = _digits_of(a).reshape(rows, 1, _D)
-    db = _digits_of(b).reshape(rows, 1, _D)
     dig = fq._digit_bound(in_limb)
     conv = [
         (min(d, 2 * _D - 2 - d, _D - 1) + 1) * dig * dig
@@ -613,8 +596,10 @@ def fused_mul(a, b, lazy: bool = False):
     state = _DState(conv, in_value * in_value)
     ops, state = _reduce_schedule(state, value_limit, limb_target, name)
     _final_certs(state, value_limit, limb_target, name)
-    out = _run_fused(da, db, ops, None, ())
-    return _limbs_of(out[:, 0]).reshape(batch + (fq.NLIMBS,))
+    out = _run_fused(
+        a.reshape(rows, 1, fq.NLIMBS), b.reshape(rows, 1, fq.NLIMBS), ops, None, ()
+    )
+    return out.reshape(batch + (fq.NLIMBS,))
 
 
 def execute_plan(
@@ -635,8 +620,6 @@ def execute_plan(
     batch = A.shape[:-2]
     rows = _rows_of(batch)
     L = len(plan.a_rows)
-    A_d = _digits_of(A).reshape((rows, L, _D))
-    B_d = _digits_of(B).reshape((rows, L, _D))
 
     # conv digit bounds per position, one lane-uniform state
     dig_a, dig_b = fq._digit_bound(ba.limb), fq._digit_bound(bb.limb)
@@ -680,6 +663,7 @@ def execute_plan(
     else:  # pragma: no cover - static schedule
         raise AssertionError(f"{kname}: pallas out-lincomb does not fit f32")
     w = len(lane_state.digits)
+    _lane_cert(w, kname)
 
     # out-row bound profiles + digit-space borrow constants
     def profile(idx):
@@ -737,10 +721,14 @@ def execute_plan(
     )
     _final_certs(out_state, value_limit, limb_target, kname)
 
-    Ain_d = None
+    Ain = None
     if has_pass:
-        a_full = jnp.broadcast_to(a, batch + a.shape[-2:])
-        Ain_d = _digits_of(a_full).reshape((rows, n_pass, _D))
-    key = _out_key(R, mpos, mneg, oconst, n_pass, _D)
-    out = _run_fused(A_d, B_d, pre_ops, key, post_ops, Ain_d)
-    return _limbs_of(out).reshape(batch + (R, fq.NLIMBS))
+        Ain = jnp.broadcast_to(a, batch + a.shape[-2:]).reshape(
+            rows, n_pass, fq.NLIMBS
+        )
+    key = _out_key(R, mpos, mneg, oconst, n_pass)
+    out = _run_fused(
+        A.reshape(rows, L, fq.NLIMBS), B.reshape(rows, L, fq.NLIMBS),
+        pre_ops, key, post_ops, Ain,
+    )
+    return out.reshape(batch + (R, fq.NLIMBS))

@@ -363,7 +363,7 @@ def _lincomb_bounds(rows: list[LC], bound_for, name: str):
 
 def _lincomb_matrices(rows: list[LC], n_in: int):
     """Split the integer row matrix into positive / negative-magnitude halves
-    (M_pos - M_neg). uint64 so the dot stays in the limb dtype."""
+    (M_pos - M_neg)."""
     m_pos = np.zeros((len(rows), n_in), dtype=np.uint64)
     m_neg = np.zeros((len(rows), n_in), dtype=np.uint64)
     for r, lc in enumerate(rows):
@@ -375,29 +375,46 @@ def _lincomb_matrices(rows: list[LC], n_in: int):
     return m_pos, m_neg
 
 
-def _apply_matrices(m_pos, m_neg, consts, x):
-    """rows @ x as two constant-matrix dot_generals plus the borrow constants:
-    out[..., r, :] = (M_pos @ x) + (C_r - M_neg @ x). The dot form emits ~5 HLO
-    ops per lincomb where the term-by-term form emitted hundreds (slice +
-    scale + add per coefficient) — program size was the r3 compile bottleneck.
-
-    Dtype follows x: an f64 operand gets f64 matrices/constants (exact — every
-    bound is asserted < 2^53 by the callers), keeping the pipeline off u64
-    multiplies, which have no SIMD path on CPU."""
-    f64 = x.dtype == jnp.float64
-    dt = jnp.float64 if f64 else jnp.uint64
+def _contract(m, x, dt):
+    """m @ x over x's row axis (-2), in dtype dt: [..., n, w] -> [..., r, w].
+    u64 has no dot on TPU (XLA's X64 rewriter has no dot_general rule), so
+    the u64 form is an unrolled broadcast-multiply-sum."""
+    if dt == jnp.uint64:
+        return sum(
+            x[..., i : i + 1, :] * m[:, i, None]
+            for i in range(m.shape[1])
+            if m[:, i].any()
+        )
     dn = (((1,), (x.ndim - 2,)), ((), ()))
-    pos = jax.lax.dot_general(
-        jnp.asarray(m_pos, dtype=dt), x, dn, preferred_element_type=dt
+    out = jax.lax.dot_general(
+        jnp.asarray(m, dtype=dt), x.astype(dt), dn, preferred_element_type=dt
     )
-    pos = jnp.moveaxis(pos, 0, -2)
-    if not m_neg.any():
-        return pos
-    neg = jax.lax.dot_general(
-        jnp.asarray(m_neg, dtype=dt), x, dn, preferred_element_type=dt
-    )
-    neg = jnp.moveaxis(neg, 0, -2)
-    return pos + (jnp.asarray(consts, dtype=dt) - neg)
+    return jnp.moveaxis(out, 0, -2)
+
+
+def _apply_matrices(m_pos, m_neg, consts, x, acc_bound: int):
+    """rows @ x as two constant-matrix contractions plus the borrow
+    constants: out[..., r, :] = (M_pos @ x) + (C_r - M_neg @ x). The dot form
+    emits ~5 HLO ops per lincomb where the term-by-term form emitted hundreds
+    (slice + scale + add per coefficient) — program size was the r3 compile
+    bottleneck.
+
+    ``acc_bound`` bounds every accumulator (and every input a non-zero
+    coefficient reads). An f64 operand stays f64 (exact below 2^53, asserted
+    by the callers) — the CPU pipeline stays off u64 multiplies, which have
+    no SIMD path there. An integer operand contracts in u32 when the bound
+    fits (the input lincombs: limbs < 2^22), else in u64 (the wide
+    out-lincomb of the digits/shear backends); the result keeps x's dtype."""
+    if x.dtype == jnp.float64:
+        dt = jnp.float64
+    elif acc_bound < 1 << 32:
+        dt = jnp.uint32
+    else:
+        dt = jnp.uint64
+    pos = _contract(m_pos, x, dt)
+    if m_neg.any():
+        pos = pos + (jnp.asarray(consts, dtype=dt) - _contract(m_neg, x, dt))
+    return pos.astype(x.dtype)
 
 
 def lincomb(rows: list[LC], x, in_bound: _Bound, name: str = "", bound_for=None) -> tuple:
@@ -406,8 +423,12 @@ def lincomb(rows: list[LC], x, in_bound: _Bound, name: str = "", bound_for=None)
     per-index input bound (default: in_bound for all indices)."""
     bound_for = bound_for or (lambda _i: in_bound)
     consts, worst = _lincomb_bounds(rows, bound_for, name)
+    if x.dtype != jnp.float64:
+        # the integer contraction runs in u32: every partial sum, borrow
+        # constant and read input is bounded by the output limb bound
+        fq._cert("lincomb_u32_exact", worst.limb, (1 << 32) - 1, note=name)
     m_pos, m_neg = _lincomb_matrices(rows, x.shape[-2])
-    return _apply_matrices(m_pos, m_neg, consts, x), worst
+    return _apply_matrices(m_pos, m_neg, consts, x, worst.limb), worst
 
 
 def append_const_pool(plan: Plan, b):
@@ -608,7 +629,7 @@ def execute(
         ), f"{name}: wide accumulator bound 2^{limb.bit_length()}"
         worst_limb = max(worst_limb, limb)
     m_pos, m_neg = _lincomb_matrices(out_rows, T.shape[-2])
-    out = _apply_matrices(m_pos, m_neg, consts, T)
+    out = _apply_matrices(m_pos, m_neg, consts, T, worst_limb)
     value_bound = sum(worst_limb << (16 * i) for i in range(n_wide))
     if out_bound is None:
         return fq.reduce_limbs(out, [worst_limb] * n_wide, value_bound)

@@ -16,7 +16,7 @@ Policy per classified fault kind (``faults.classify``):
 * HANG       — watchdog fired; the worker thread may be stranded inside the
   device client forever (it cannot be killed). Descend immediately; the
   stranded-thread count is capped (``max_hung_threads``) — past the cap the
-  domain is hard-quarantined so a wedged tunnel cannot accumulate threads.
+  domain is hard-quarantined so a wedged device cannot accumulate threads.
 * CORRUPTION — device numerics suspect; jump straight to the LAST rung
   (CPU fallback) and quarantine.
 
@@ -75,8 +75,8 @@ class HealthState(IntEnum):
 def _default_deadline() -> float:
     # generous by default: a COLD first call legitimately spends minutes in
     # XLA compilation (the r3 pathology hit 461 s at toy shape) — the
-    # watchdog must catch wedged-forever, not slow-compile. Benches and the
-    # hunter tighten it via the env var once caches are warm.
+    # watchdog must catch wedged-forever, not slow-compile. Benches tighten
+    # it via the env var once caches are warm.
     return float(os.environ.get("LIGHTHOUSE_WATCHDOG_S", "600"))
 
 
@@ -262,7 +262,7 @@ class BackendSupervisor:
                     self._hung_threads += 1
                     self.watchdog_timeouts += 1
                     if self._hung_threads >= self.config.max_hung_threads:
-                        # a wedged tunnel must not accumulate threads
+                        # a wedged device must not accumulate threads
                         self._hard_quarantined = True
                         self._set_state(HealthState.QUARANTINED)
                     fire = True
@@ -457,8 +457,8 @@ def reset_all() -> None:
 
 
 def run_with_deadline(stage: str, fn, deadline_s: float):
-    """Standalone watchdog call (no health machine): used by the TPU hunter
-    to bound probe helpers — raises ``WatchdogTimeout`` on a hang."""
+    """Standalone watchdog call (no health machine): bounds the mesh tier's
+    per-device probes — raises ``WatchdogTimeout`` on a hang."""
     box: dict = {}
     done = threading.Event()
 

@@ -118,8 +118,7 @@ class TestCertifier:
         """The third backend regime: the representative graph subset
         re-executes THROUGH the fused pallas kernels (plans.execute and
         mont_mul dispatch there under LIGHTHOUSE_CONV_IMPL=pallas) and
-        stays green — the full pallas sweep is the analysis CLI's (and the
-        hunter preflight's) job."""
+        stays green — the full pallas sweep is the analysis CLI's job."""
         cert = bounds.certify(
             backends=("pallas",),
             batches=(1, 32),
@@ -688,8 +687,7 @@ class TestSixPassSuite:
     def test_cli_green_certificate(self, tmp_path):
         """``python -m lighthouse_tpu.analysis --json`` runs all six passes
         (bounds, hygiene, recompile, supervisor, concurrency, memory) end to
-        end and the certificate is green — a red cert fails tier-1, which is
-        exactly what keeps the hunter preflight (memoized per HEAD) honest.
+        end and the certificate is green — a red cert fails tier-1.
         The bounds + memory passes are restricted to a representative graph
         subset at batch 1 to stay inside the tier-1 wall clock; the full
         sweeps are TestCertifier's / TestMemoryCertifier's job."""
@@ -732,7 +730,7 @@ class TestSixPassSuite:
         mc = _json.loads(mem_out.read_text())
         assert mc["ok"] and mc["n_failed"] == 0
         # the restricted run still covers all three conv backends, every
-        # residency family, and emits the planner the hunter gate consumes
+        # residency family, and emits the planner rung_fit consumes
         regimes = {
             r["graph"].split("/", 1)[0]
             for r in mc["rows"] if r["kind"] == "graph_footprint"
@@ -905,7 +903,7 @@ from lighthouse_tpu.analysis import memory as amem  # noqa: E402
 
 # representative tier-1 subset: one fq graph, one tower graph, the fused
 # pallas entries (exercises the VMEM sink). The full sweep rides the slow
-# lane + the hunter preflight.
+# lane.
 _MEM_GRAPHS = ["fq.mont_mul", "tower.fq2_mul", "pallas.fused_mul"]
 
 
@@ -949,7 +947,7 @@ class TestMemoryCertifier:
     @pytest.mark.slow
     def test_full_cert_every_registry_graph(self):
         """The acceptance sweep: EVERY bounds-registry graph certifies under
-        all three backends x both batch regimes (the hunter preflight's
+        all three backends x both batch regimes (the analysis CLI's
         default-on configuration)."""
         cert = amem.certify_memory()
         bad = [r for r in cert["rows"] if not r["ok"]]
@@ -990,12 +988,12 @@ class TestMemoryCertifier:
 
     def test_mutation_oversized_vmem_tile_fails(self, monkeypatch):
         """Seeded over-budget mutation #3: an undeclared-tier pallas tile
-        (row tile forced to 2048 — a ~24 MB in-kernel working set vs the
-        declared 16 MiB VMEM cap) must turn the cert red on its VMEM
-        row."""
+        (row tile forced to 8192 — ~24 MiB of double-buffered operand
+        blocks vs the declared 16 MiB VMEM cap) must turn the cert red on
+        its VMEM row."""
         from lighthouse_tpu.ops.bls import pallas_kernels as pk
 
-        monkeypatch.setattr(pk, "_row_tile", lambda rows, L: 2048)
+        monkeypatch.setattr(pk, "_row_tile", lambda rows, L: 8192)
         cert = amem.certify_memory(
             backends=("pallas",), batches=(1,), graphs=["pallas.fused_mul"]
         )
@@ -1022,7 +1020,7 @@ class TestMemoryCertifier:
             assert batches == sorted(batches), (graph, batches)
 
     def test_rung_fit_gates_oversized_shapes(self, monkeypatch):
-        """The hunter's gate arithmetic: a 1M-validator slasher rung at the
+        """The rung gate arithmetic: a 1M-validator slasher rung at the
         reference 4096-epoch history (~32 GB of span planes) cannot fit the
         16 GiB tpu_v5e tier, while the 32k rung fits with margin."""
         monkeypatch.setenv("BENCH_SLASHER_HISTORY", "4096")
@@ -1192,33 +1190,3 @@ class TestBoundedCacheAudit:
         assert us.prune_hot(8) == 92
         assert len(us) == 8
 
-
-class TestHunterMemoryGate:
-    def test_unfittable_rung_skipped_with_logged_verdict(
-        self, monkeypatch, tmp_path
-    ):
-        """Acceptance: the hunter's fit-gate rejects a real ladder rung
-        whose shape cannot fit the declared tier, and the skip verdict
-        lands in the window log — the shape is never dispatched."""
-        import tools_tpu_hunter as hunter
-
-        monkeypatch.setenv("BENCH_SLASHER_HISTORY", "4096")
-        monkeypatch.setattr(hunter, "MEMORY_TIER", "tpu_v5e")
-        log_path = tmp_path / "TPU_WINDOW_LOG.jsonl"
-        monkeypatch.setattr(hunter, "LOG", str(log_path))
-        idx = next(
-            i for i, r in enumerate(hunter.RUNGS)
-            if r[5] == "slasher" and r[2] >= 1_000_000
-        )
-        verdict = hunter.rung_fit_verdict(idx)
-        assert not verdict["fits"], verdict
-        assert verdict["margin_bytes"] < 0
-        # the main loop's skip branch logs exactly this verdict
-        hunter.log("rung_skipped_unfittable", rung=idx, **verdict)
-        import json as _json
-
-        rec = _json.loads(log_path.read_text().splitlines()[-1])
-        assert rec["event"] == "rung_skipped_unfittable"
-        assert rec["rung"] == idx and rec["fits"] is False
-        # the smallest rung still passes the gate (a window is spent)
-        assert hunter.rung_fit_verdict(0)["fits"]

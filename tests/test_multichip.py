@@ -10,37 +10,14 @@ one poisoned set fails the whole batch.
 import numpy as np
 import pytest
 
-
 import jax
 
 from lighthouse_tpu.bls.tpu_backend import verify_signature_sets_sharded
 from lighthouse_tpu.ops.bls import g2
 
 
-def _has_native_shard_map() -> bool:
-    try:
-        from jax import shard_map  # noqa: F401
-
-        return True
-    except ImportError:
-        return False
-
-
-# JAX compile-heavy tier (see pytest.ini). On jax builds without the
-# top-level shard_map (< 0.5), the production kernels fall back to
-# jax.experimental.shard_map with check_rep=False (tpu_backend._shard_map),
-# but the mesh tier SKIPS: the experimental tracer lacks replication rules
-# for several primitives and the fallback compiles are minutes-long — they
-# used to FAIL tier-1 outright on such builds (ImportError), and running
-# them would blow its wall-clock budget instead.
-pytestmark = [
-    pytest.mark.kernel,
-    pytest.mark.skipif(
-        not _has_native_shard_map(),
-        reason="jax build lacks jax.shard_map (sharded mesh tier skipped; "
-        "production code uses the experimental fallback)",
-    ),
-]
+# JAX compile-heavy tier (see pytest.ini).
+pytestmark = pytest.mark.kernel
 
 
 @pytest.fixture(scope="module")

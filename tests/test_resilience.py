@@ -110,15 +110,15 @@ class TestClassifier:
         assert classify(ValueError("totally novel")) == FaultKind.TRANSIENT
 
     def test_subprocess_note_classification(self):
-        # the hunter's probe/bench notes (bench.probe_once / run_inner)
+        # bench.py's rung notes (run_inner)
         assert classify_text("probe hung (> 120s)") == FaultKind.HANG
         assert classify_text("shape (16x64) exceeded 1800s") == FaultKind.HANG
         assert classify_text(
             "probe exited rc=1: RESOURCE_EXHAUSTED"
         ) == FaultKind.OOM
         # OOM outranks the generic hang markers: "limit exceeded" inside a
-        # RESOURCE_EXHAUSTED status must NOT send the hunter to a bigger
-        # rung (which would just OOM again)
+        # RESOURCE_EXHAUSTED status must NOT be read as a hang (a retried
+        # shape would just OOM again)
         assert classify_text(
             "RESOURCE_EXHAUSTED: memory limit exceeded while allocating"
         ) == FaultKind.OOM
