@@ -5,11 +5,12 @@ library users call, against a 1,048,576-validator registry (BASELINE.json's
 "1M validators") held on the device:
 
 1. Registry: keys from ``--seed`` on the native C++ backend (worker processes
-   that never import JAX), loaded with ``device_pubkeys_from_raw``.
+   that never import JAX, running while 2 and 3 do), loaded with
+   ``device_pubkeys_from_raw``.
 2. Kernel parity: the fused Pallas field multiply and an fq12 multiply
    against host integer arithmetic on a small input.
 3. Compile: the h2c / prep / pair stages at n_pad=64, k_pad=512, compiled by
-   hand for their seconds and ``memory_analysis()``.
+   hand, all at once, for their seconds and ``memory_analysis()``.
 4. Traffic: 4 batches of 64 aggregate sets x 512 attesters through the
    chain's ``_batch_verify_items_inner`` (the ``bls_supervisor()`` ladder),
    plus a batch with two signatures swapped. Every verdict must match the
@@ -20,7 +21,8 @@ library users call, against a 1,048,576-validator registry (BASELINE.json's
 
 ``--four-chips`` runs only the sharded serving tier (the chain's mesh
 planner with LIGHTHOUSE_MESH_DEVICES=4) on the same registry and batches,
-compared with single-device ``verify_indexed_sets_device`` on device 0.
+compared with single-device ``verify_indexed_sets_device`` on device 0; it
+compiles the sharded stages beside the single-device ones.
 
 The last line of stdout is ``{"ok": true, "device": {...}}``. Any failure
 raises and exits non-zero before it; so does a run where JAX finds no TPU.
@@ -29,12 +31,15 @@ raises and exits non-zero before it; so does a run where JAX finds no TPU.
 from __future__ import annotations
 
 import argparse
+import faulthandler
 import importlib.util
 import json
 import multiprocessing
 import os
+import signal
 import sys
 import time
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
@@ -80,21 +85,26 @@ def _pubkeys(sk_rows: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     return comp, raw
 
 
-def build_registry(n: int, seed: int, workers: int):
+def build_registry(n: int, seed: int):
     """Secret keys [n, 32] (31 random bytes: below the group order r) and
-    their compressed / raw pubkeys, spread over spawned workers."""
+    their compressed / raw pubkeys, made by spawned workers while the caller
+    goes on. Returns a function that waits for them: (sks, comp, raw)."""
+    workers = os.cpu_count() or 1
     rng = np.random.default_rng(seed)
     sks = rng.integers(0, 256, size=(n, 32), dtype=np.uint8)
     sks[:, 0] = 0
     sks[:, 31] |= 1  # never the zero key
     _native_module().load_bls()  # build the library once, before the pool
-    chunks = np.array_split(sks, max(1, workers * 4))
-    ctx = multiprocessing.get_context("spawn")
-    with ctx.Pool(workers) as pool:
-        parts = pool.map(_pubkeys, chunks)
-    comp = np.concatenate([p[0] for p in parts])
-    raw = np.concatenate([p[1] for p in parts])
-    return sks, comp, raw
+    pool = multiprocessing.get_context("spawn").Pool(workers)
+    parts = pool.map_async(_pubkeys, np.array_split(sks, workers * 4))
+
+    def wait():
+        with pool:
+            got = parts.get()
+        return (sks, np.concatenate([p[0] for p in got]),
+                np.concatenate([p[1] for p in got]))
+
+    return wait
 
 
 def build_batches(sks, n_batches: int, sets: int, keys: int, seed: int):
@@ -203,10 +213,12 @@ def kernel_parity() -> None:
 
 
 def compile_stages(lowerings) -> None:
-    """Compile each (name, Lowered) by hand: its seconds and memory. The
-    persistent compile cache then serves the jitted call that follows."""
-    for name, lowered in lowerings:
-        log(f"compiling {name} ...")
+    """Compile every (name, Lowered) by hand, all at once on host threads:
+    the seconds and memory of each. The persistent compile cache then
+    serves the jitted calls that follow."""
+
+    def one(item):
+        name, lowered = item
         t0 = time.perf_counter()
         ma = lowered.compile().memory_analysis()
         log(
@@ -214,6 +226,10 @@ def compile_stages(lowerings) -> None:
             f"args {ma.argument_size_in_bytes} B, temp {ma.temp_size_in_bytes} B, "
             f"code {ma.generated_code_size_in_bytes} B"
         )
+
+    log(f"compiling {[name for name, _ in lowerings]} concurrently ...")
+    with ThreadPoolExecutor(len(lowerings)) as ex:
+        list(ex.map(one, lowerings))
 
 
 def library_seam(sks, comp) -> None:
@@ -260,14 +276,10 @@ def run_one_chip(chain, raw, batches) -> None:
             f"sets/s after warm-up (host clock, 1 chip)")
 
 
-def run_four_chips(chain, cache, batches) -> None:
-    import jax
-
-    from lighthouse_tpu.bls import mesh as bls_mesh, tpu_backend as tb
-
-    # the mesh ladder splits 64 one-item groups into 4 shards of 16
-    mesh = bls_mesh.get_mesh((0, 1, 2, 3))
-    compile_stages(tb.sharded_stage_lowerings(mesh, SETS, KEYS, N_VALIDATORS))
+def run_four_chips(chain, single, batches) -> None:
+    """The chain's mesh path against single-device verification of the
+    same batches on device 0 (``single``: the registry there)."""
+    from lighthouse_tpu.bls import tpu_backend as tb
 
     placements = []
     real = tb.verify_staged_pershard
@@ -279,18 +291,20 @@ def run_four_chips(chain, cache, batches) -> None:
     tb.verify_staged_pershard = spy
     try:
         check(chain._mesh_planner() is not None, "mesh planner is off")
-        single = jax.device_put(cache, jax.devices()[0])
         for b, items in enumerate(batches + [swapped(batches[0])]):
             log(f"batch {b}: mesh ...")
             t0 = time.perf_counter()
             mesh_ok = chain._batch_verify_items_inner(items)
             dt = time.perf_counter() - t0
+            t0 = time.perf_counter()
             one_ok = tb.verify_indexed_sets_device(single, items)
+            dt1 = time.perf_counter() - t0
             want = b < len(batches)
             check(mesh_ok is want and one_ok is want, (
                 f"batch {b}: mesh {mesh_ok}, single {one_ok}, want {want}"
             ))
-            log(f"batch {b}: mesh {mesh_ok} == single {one_ok} ({dt:.2f} s mesh)")
+            log(f"batch {b}: mesh {mesh_ok} == single {one_ok} "
+                f"({dt:.3f} s mesh, {dt1:.3f} s single)")
     finally:
         tb.verify_staged_pershard = real
     check(placements and all(len(p) == 4 for p in placements), f"placements {placements}")
@@ -302,8 +316,9 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=2026)
     ap.add_argument("--four-chips", action="store_true",
                     help="run only the 4-chip sharded serving tier")
-    ap.add_argument("--workers", type=int, default=min(16, os.cpu_count() or 1))
     args = ap.parse_args()
+    # a run stopped by a time limit says where every thread was
+    faulthandler.register(signal.SIGTERM, all_threads=True, chain=True)
 
     import jax
 
@@ -314,48 +329,9 @@ def main() -> int:
     want = 4 if args.four_chips else 1
     if len(devices) < want:
         raise SystemExit(f"need {want} chips, JAX found {len(devices)}")
-    if args.four_chips:
-        os.environ["LIGHTHOUSE_MESH_DEVICES"] = "4"
     log(f"device: {dev.device_kind} x {len(devices)} ({jax.__version__})")
 
-    t0 = time.perf_counter()
-    sks, comp, raw = build_registry(N_VALIDATORS, args.seed, args.workers)
-    log(f"registry: {N_VALIDATORS} keys in {time.perf_counter() - t0:.1f} s "
-        f"on {args.workers} workers")
-
-    from lighthouse_tpu.beacon_chain.pubkey_cache import device_pubkeys_from_raw
-    from lighthouse_tpu.ops.bls import fq, pallas_kernels
-
-    check(fq.conv_backend() == "pallas", f"conv backend {fq.conv_backend()}")
-    check(not pallas_kernels._interpret(), "pallas kernels in interpret mode")
-
-    t0 = time.perf_counter()
-    batches = build_batches(sks, N_BATCHES, SETS, KEYS, args.seed)
-    log(f"traffic: {N_BATCHES} x {SETS} sets x {KEYS} keys in "
-        f"{time.perf_counter() - t0:.1f} s")
-
-    t0 = time.perf_counter()
-    cache = device_pubkeys_from_raw(raw)
-    if args.four_chips:
-        from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-        mesh = Mesh(np.array(devices[:4]), ("sets",))
-        cache = jax.device_put(cache, NamedSharding(mesh, PartitionSpec()))
-    cache.block_until_ready()
-    check(cache.shape == (N_VALIDATORS, 3, 25), f"cache shape {cache.shape}")
-    log(f"registry on device: {cache.nbytes} B in {time.perf_counter() - t0:.1f} s")
-
-    chain = registry_chain(cache)
-    if args.four_chips:
-        run_four_chips(chain, cache, batches)
-    else:
-        from lighthouse_tpu.bls import tpu_backend as tb
-
-        kernel_parity()
-        compile_stages(tb.stage_lowerings(SETS, KEYS, N_VALIDATORS))
-        run_one_chip(chain, raw, batches)
-        library_seam(sks, comp)
-    check_supervisors()
+    smoke(args.seed, args.four_chips)
 
     for d in devices[:want]:
         stats = d.memory_stats() or {}
@@ -366,6 +342,61 @@ def main() -> int:
                    "count": len(devices)},
     }))
     return 0
+
+
+def smoke(seed: int, four_chips: bool) -> None:
+    """Every phase of the check; any failure raises."""
+    import jax
+
+    if four_chips:
+        os.environ["LIGHTHOUSE_MESH_DEVICES"] = "4"
+    t0 = time.perf_counter()
+    registry = build_registry(N_VALIDATORS, seed)
+
+    from lighthouse_tpu.beacon_chain.pubkey_cache import device_pubkeys_from_raw
+    from lighthouse_tpu.bls import mesh as bls_mesh, tpu_backend as tb
+    from lighthouse_tpu.ops.bls import fq, pallas_kernels
+
+    check(fq.conv_backend() == "pallas", f"conv backend {fq.conv_backend()}")
+    check(not pallas_kernels._interpret(), "pallas kernels in interpret mode")
+
+    # the stages compile while the workers make the keys
+    lowerings = tb.stage_lowerings(SETS, KEYS, N_VALIDATORS)
+    if four_chips:
+        # the mesh ladder splits 64 one-item groups into 4 shards of 16
+        mesh = bls_mesh.get_mesh(range(4))
+        lowerings = [
+            (f"mesh {name}", lowered) for name, lowered
+            in tb.sharded_stage_lowerings(mesh, SETS, KEYS, N_VALIDATORS)
+        ] + lowerings
+    else:
+        kernel_parity()
+    compile_stages(lowerings)
+
+    sks, comp, raw = registry()
+    log(f"registry: {N_VALIDATORS} keys {time.perf_counter() - t0:.1f} s "
+        f"after the workers started")
+
+    t0 = time.perf_counter()
+    batches = build_batches(sks, N_BATCHES, SETS, KEYS, seed)
+    log(f"traffic: {N_BATCHES} x {SETS} sets x {KEYS} keys in "
+        f"{time.perf_counter() - t0:.1f} s")
+
+    t0 = time.perf_counter()
+    cache = device_pubkeys_from_raw(raw)
+    cache.block_until_ready()
+    check(cache.shape == (N_VALIDATORS, 3, 25), f"cache shape {cache.shape}")
+    log(f"registry on device: {cache.nbytes} B in {time.perf_counter() - t0:.1f} s")
+
+    if four_chips:
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        replicated = jax.device_put(cache, NamedSharding(mesh, PartitionSpec()))
+        run_four_chips(registry_chain(replicated), cache, batches)
+    else:
+        run_one_chip(registry_chain(cache), raw, batches)
+        library_seam(sks, comp)
+    check_supervisors()
 
 
 if __name__ == "__main__":

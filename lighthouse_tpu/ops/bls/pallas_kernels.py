@@ -341,8 +341,8 @@ def _lane_cert(width: int, name: str) -> None:
     row with its top lane free: a carry-save split rolls every lane up one,
     and lane 127 wraps to lane 0."""
     assert fq._cert(
-        "pallas_lane_width", width, _W, note=name
-    ), f"{name}: pallas digit plane exceeds {_W} lanes"
+        "pallas_lane_width", width, _W - 1, note=name
+    ), f"{name}: pallas digit plane exceeds {_W - 1} lanes"
 
 
 def _roll(t, k: int):

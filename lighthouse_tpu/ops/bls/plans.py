@@ -392,25 +392,19 @@ def _contract(m, x, dt):
     return jnp.moveaxis(out, 0, -2)
 
 
-def _apply_matrices(m_pos, m_neg, consts, x, acc_bound: int):
+def _apply_matrices(m_pos, m_neg, consts, x, dt):
     """rows @ x as two constant-matrix contractions plus the borrow
     constants: out[..., r, :] = (M_pos @ x) + (C_r - M_neg @ x). The dot form
     emits ~5 HLO ops per lincomb where the term-by-term form emitted hundreds
     (slice + scale + add per coefficient) — program size was the r3 compile
     bottleneck.
 
-    ``acc_bound`` bounds every accumulator (and every input a non-zero
-    coefficient reads). An f64 operand stays f64 (exact below 2^53, asserted
-    by the callers) — the CPU pipeline stays off u64 multiplies, which have
-    no SIMD path there. An integer operand contracts in u32 when the bound
-    fits (the input lincombs: limbs < 2^22), else in u64 (the wide
-    out-lincomb of the digits/shear backends); the result keeps x's dtype."""
+    An f64 operand contracts in f64 (exact below 2^53, asserted by the
+    callers) — the CPU pipeline stays off u64 multiplies, which have no SIMD
+    path there. An integer operand contracts in ``dt``, which the caller
+    picks from its own bounds; the result keeps x's dtype."""
     if x.dtype == jnp.float64:
         dt = jnp.float64
-    elif acc_bound < 1 << 32:
-        dt = jnp.uint32
-    else:
-        dt = jnp.uint64
     pos = _contract(m_pos, x, dt)
     if m_neg.any():
         pos = pos + (jnp.asarray(consts, dtype=dt) - _contract(m_neg, x, dt))
@@ -423,12 +417,10 @@ def lincomb(rows: list[LC], x, in_bound: _Bound, name: str = "", bound_for=None)
     per-index input bound (default: in_bound for all indices)."""
     bound_for = bound_for or (lambda _i: in_bound)
     consts, worst = _lincomb_bounds(rows, bound_for, name)
-    if x.dtype != jnp.float64:
-        # the integer contraction runs in u32: every partial sum, borrow
-        # constant and read input is bounded by the output limb bound
-        fq._cert("lincomb_u32_exact", worst.limb, (1 << 32) - 1, note=name)
     m_pos, m_neg = _lincomb_matrices(rows, x.shape[-2])
-    return _apply_matrices(m_pos, m_neg, consts, x, worst.limb), worst
+    # an integer x contracts in u32: lincomb_limb_budget holds every partial
+    # sum, borrow constant and read input below 2^22
+    return _apply_matrices(m_pos, m_neg, consts, x, jnp.uint32), worst
 
 
 def append_const_pool(plan: Plan, b):
@@ -629,7 +621,8 @@ def execute(
         ), f"{name}: wide accumulator bound 2^{limb.bit_length()}"
         worst_limb = max(worst_limb, limb)
     m_pos, m_neg = _lincomb_matrices(out_rows, T.shape[-2])
-    out = _apply_matrices(m_pos, m_neg, consts, T, worst_limb)
+    # execute_wide_acc bounds the wide accumulators by T's cap, not 2^32
+    out = _apply_matrices(m_pos, m_neg, consts, T, jnp.uint64)
     value_bound = sum(worst_limb << (16 * i) for i in range(n_wide))
     if out_bound is None:
         return fq.reduce_limbs(out, [worst_limb] * n_wide, value_bound)
