@@ -1,0 +1,11 @@
+"""The benchmark's own checks run on the CPU, with no chip:
+
+    python3 -m pytest benchmark/tests -q
+"""
+
+import os
+import sys
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
