@@ -1,9 +1,10 @@
 """Headline benchmark: mainnet-shape batched BLS attestation verification.
 
 Prints ONE JSON line. The record is self-describing: it carries the
-platform and device kind the device path ran on, the shapes measured,
-per-stage timings, and a rough MFU estimate from XLA's own cost analysis of
-the fused kernel. Every measurement runs on a TPU: an ``--inner`` process
+platform and device kind the device path ran on, the shapes measured, and
+a rough MFU estimate from XLA's own cost analysis of the fused kernel
+(per-stage device time comes from a profiler trace of the program's own
+spans and stage modules: ``benchmark/programtrace.py``). Every measurement runs on a TPU: an ``--inner`` process
 that finds none exits non-zero, and bench.py then prints a failure record
 and exits non-zero. The parent never touches JAX (a parent holding the chip
 would lock its children out).
@@ -17,9 +18,6 @@ would lock its children out).
                   32-core north star at vs_baseline ~200)
     platform      jax platform the device path ran on ("tpu")
     shape         {sets, keys_per_set, validators, batch}
-    stages        per-stage milliseconds for one batch (host hashing, parse,
-                  device h2c map, gather+aggregate, decompress, prologue
-                  subgroup/scale/sum, Miller loops, final exponentiation)
     mfu_estimate  fused-kernel FLOP/s (XLA cost analysis) / the device
                   kind's peak (null for a kind not in _PEAK_FLOPS) —
                   indicative only: the kernel is limb arithmetic, not bf16
@@ -236,95 +234,6 @@ def _time_stage(fn, *args, iters: int = 3) -> float:
     return (time.perf_counter() - t0) / iters * 1e3
 
 
-def _stage_breakdown(cache, idx, msgs, sigs) -> dict:
-    """Per-stage timings (ms per BATCH) of the verification chain, each
-    stage jitted separately. Sums will exceed the fused end-to-end cost —
-    fusion removes intermediates — but the ratios aim the optimization."""
-    import functools
-
-    import jax
-    import jax.numpy as jnp
-
-    from lighthouse_tpu.bls.serde import parse_g2_bytes, raw_to_mont
-    from lighthouse_tpu.ops.bls import curve, g1, g2, h2c, pairing
-    from lighthouse_tpu.bls import tpu_backend as tb
-    from lighthouse_tpu.ops.bls_oracle.ciphersuite import DST
-
-    n = BATCH
-    k = idx.shape[1]
-    stages = {}
-
-    msg_list = [msgs[s].tobytes() for s in range(n)]
-    t0 = time.perf_counter()
-    for _ in range(3):
-        u0, u1 = h2c.hash_to_field_batch(msg_list, DST)
-    stages["host_hash_to_field"] = (time.perf_counter() - t0) / 3 * 1e3
-
-    sig_bytes = np.asarray(sigs[:n], dtype=np.uint8)
-    t0 = time.perf_counter()
-    for _ in range(3):
-        parsed = parse_g2_bytes(sig_bytes)
-    stages["host_parse_sig"] = (time.perf_counter() - t0) / 3 * 1e3
-
-    idx_d = jnp.asarray(idx[:n])
-    mask = jnp.ones((n, k), dtype=bool)
-    scalars = jnp.asarray(_scalars(n))
-    valid = jnp.ones((n,), dtype=bool)
-
-    map_fn = jax.jit(h2c.map_to_g2)
-    stages["h2c_map_to_g2"] = _time_stage(map_fn, u0, u1)
-    mg2 = map_fn(u0, u1)
-    mxa, mya = jax.jit(g2.to_affine)(mg2)
-
-    @jax.jit
-    def gather_agg(cache, idx_d, mask):
-        pts = cache[idx_d]
-        return curve.point_sum(1, jnp.moveaxis(pts, 1, 0), jnp.moveaxis(mask, 1, 0))
-
-    stages["gather_aggregate"] = _time_stage(gather_agg, cache, idx_d, mask)
-    pk_agg = gather_agg(cache, idx_d, mask)
-
-    @jax.jit
-    def decomp(x_c0, x_c1, s_flag):
-        x_mont = raw_to_mont(jnp.stack([x_c0, x_c1], axis=-2))
-        return g2.decompress(x_mont, s_flag)
-
-    stages["sig_decompress"] = _time_stage(
-        decomp,
-        jnp.asarray(parsed["x_c0"]),
-        jnp.asarray(parsed["x_c1"]),
-        jnp.asarray(parsed["s_flag"]),
-    )
-    sig, _ = decomp(
-        jnp.asarray(parsed["x_c0"]),
-        jnp.asarray(parsed["x_c1"]),
-        jnp.asarray(parsed["s_flag"]),
-    )
-
-    prologue = jax.jit(tb._set_prologue)
-    stages["prologue_subgroup_scale"] = _time_stage(
-        prologue, pk_agg, sig, scalars, valid
-    )
-    _, pk_scaled, sig_acc = prologue(pk_agg, sig, scalars, valid)
-
-    pkx, pky = jax.jit(g1.to_affine)(pk_scaled)
-    sax, say = jax.jit(g2.to_affine)(sig_acc)
-    px = jnp.concatenate([pkx[:, 0, :], tb._MG1_X[None]], axis=0)
-    py = jnp.concatenate([pky[:, 0, :], tb._MG1_Y[None]], axis=0)
-    qx = jnp.concatenate([mxa, sax[None]], axis=0)
-    qy = jnp.concatenate([mya, say[None]], axis=0)
-    # the verify path (multi_pairing_is_one) runs the backend-dispatched
-    # product Miller stage: shared-accumulator walk on the digit backend,
-    # batched independent accumulators + product tree on the f64 CPU path
-    miller = jax.jit(pairing.miller_product)
-    stages["miller_loops"] = _time_stage(miller, px, py, qx, qy)
-    f = miller(px, py, qx, qy)
-
-    final_exp = jax.jit(pairing.final_exponentiation)
-    stages["final_exponentiation"] = _time_stage(final_exp, f)
-    return {k2: round(v, 2) for k2, v in stages.items()}
-
-
 def _kernel_flops(cache, items) -> float:
     """XLA's own FLOP estimate for the fused batch kernel (one dispatch)."""
     import jax.numpy as jnp
@@ -348,8 +257,8 @@ def _kernel_flops(cache, items) -> float:
         return 0.0
 
 
-def _bench_device(pks_raw, idx, msgs, sigs) -> tuple[float, dict, float, str]:
-    """Returns (sets/s, stage breakdown, fused-kernel FLOPs/batch, platform)."""
+def _bench_device(pks_raw, idx, msgs, sigs) -> tuple[float, float, str]:
+    """Returns (sets/s, fused-kernel FLOPs/batch, platform)."""
     import jax
 
     from lighthouse_tpu.beacon_chain.pubkey_cache import device_pubkeys_from_raw
@@ -381,9 +290,8 @@ def _bench_device(pks_raw, idx, msgs, sigs) -> tuple[float, dict, float, str]:
         ok = tb.verify_indexed_sets_device(cache, items_all[off : off + BATCH])
         assert ok, f"device batch at {off} rejected"
     dt = time.perf_counter() - t0
-    stages = _stage_breakdown(cache, idx, msgs, sigs)
     flops = _kernel_flops(cache, items_all[:BATCH])
-    return N_SETS / dt, stages, flops, platform
+    return N_SETS / dt, flops, platform
 
 
 def _bench_native(pks_raw, idx, msgs, sigs) -> float:
@@ -493,7 +401,7 @@ def _inner():
     pks_comp, pks_raw, idx, msgs, sigs = _fixture()
     native = _bench_native(pks_raw, idx, msgs, sigs)
     print(f"# native (C++ single-core): {native:.2f} sets/s", flush=True)
-    dev, stages, flops, platform = _bench_device(pks_raw, idx, msgs, sigs)
+    dev, flops, platform = _bench_device(pks_raw, idx, msgs, sigs)
 
     import jax
 
@@ -518,7 +426,6 @@ def _inner():
                     "batch": BATCH,
                 },
                 "native_cpu_sets_per_s": round(native, 2),
-                "stages_ms_per_batch": stages,
                 "kernel_gflops_per_batch": round(flops / 1e9, 2) if flops else None,
                 "mfu_estimate": mfu,
                 "resilience": _resilience_summary(),

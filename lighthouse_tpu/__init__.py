@@ -38,6 +38,8 @@ try:
             )
         _jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
         _jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # installs the compile-phase listener (jax_compile_phase_seconds)
+    from .utils import tracing as _tracing  # noqa: F401
 except ImportError:  # the pure-Python oracle backend works without jax
     pass
 

@@ -16,10 +16,11 @@ Six passes, run in tier-1 CI (``tests/test_analysis.py``) and by hand via
   branches on tracers, unhashable static-argnum values, impure closures)
   with a ``# lint: allow(<rule>)`` pragma and a checked-in baseline.
 * **Pass 3 — recompilation sentinel** (``recompile.py``): a
-  compilation-count hook (``jax_log_compiles`` capture) asserting that
-  steady-state loops — the firehose verify pipeline, the epoch-engine
-  sweep — trigger ZERO recompiles after warm-up; ``recompile_probe()``
-  is the CLI's cheap in-process check of the capture plumbing.
+  compilation count (the compile listener's per-function counts,
+  ``utils/tracing.py``) asserting that steady-state loops — the firehose
+  verify pipeline, the epoch-engine sweep — trigger ZERO recompiles after
+  warm-up; ``recompile_probe()`` is the CLI's cheap in-process check of
+  the counting plumbing.
 * **Pass 4 — supervisor-transparency probe** (``supervised.py``): the
   resilience wrappers lint clean, add zero steady-state recompiles, and
   return the kernel's result bit for bit.

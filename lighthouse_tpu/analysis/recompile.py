@@ -3,11 +3,11 @@
 A steady-state serving loop (the firehose verify pipeline, the epoch-engine
 sweep, a bench rung) must trigger ZERO XLA compilations after warm-up —
 one stray recompile per step is exactly the hazard that burns a scarce TPU
-window on compiling instead of measuring. JAX has no public "compiles so
-far" counter, but ``jax_log_compiles`` emits one log record per actual
-backend compilation ("Compiling <name> with global shapes and types ...");
-the sentinel captures those records on the ``jax`` logger and exposes them
-as a monotonic per-kernel count.
+window on compiling instead of measuring. The program's compile listener
+(``utils.tracing``) counts every backend compilation (or persistent-cache
+load) per function in ``jax_compile_phase_seconds{phase="compile"}``; the
+sentinel reads those counts and exposes the compiles since it was entered
+as a list of function names.
 
 Usage::
 
@@ -20,93 +20,59 @@ Usage::
 
 or the one-shot helper ``steady_state_compiles(step_fn, warmup=2, steps=3)``.
 
-The capture is process-wide (XLA compilation is process-wide state);
-sentinels do not nest meaningfully and tests serialize on one.
+The counts are process-wide (XLA compilation is process-wide state), so a
+compile on another thread counts too.
 """
 
 from __future__ import annotations
 
-import logging
-import re
-import threading
+from ..utils.tracing import compile_counts
 
 __all__ = ["CompilationSentinel", "steady_state_compiles", "recompile_probe"]
 
-_COMPILE_RE = re.compile(r"^Compiling ([^\s]+) with global shapes")
 
-
-class _CaptureHandler(logging.Handler):
-    def __init__(self):
-        super().__init__(level=logging.DEBUG)
-        self.events: list[str] = []
-        self._lock2 = threading.Lock()
-
-    def emit(self, record: logging.LogRecord) -> None:
-        try:
-            m = _COMPILE_RE.match(record.getMessage())
-        except Exception:  # noqa: BLE001 — a log formatting error is not ours
-            return
-        if m:
-            with self._lock2:
-                self.events.append(m.group(1))
+def _names_since(before: dict[str, int], after: dict[str, int]) -> list[str]:
+    return [fun for fun, n in after.items()
+            for _ in range(n - before.get(fun, 0))]
 
 
 class CompilationSentinel:
     """Context manager counting XLA compilations while active."""
 
     def __init__(self):
-        self._handler = _CaptureHandler()
-        self._logger = logging.getLogger("jax")
-        self._prev_flag = None
-        self._prev_level = None
-        self._prev_propagate = None
+        self._entered: dict[str, int] = {}
+        self._exited: dict[str, int] | None = None
 
     def __enter__(self) -> "CompilationSentinel":
-        import jax
-
-        self._prev_flag = jax.config.jax_log_compiles
-        jax.config.update("jax_log_compiles", True)
-        # jax_log_compiles promotes the records to WARNING; make sure the
-        # logger does not filter them out regardless of ambient config
-        self._prev_level = self._logger.level
-        if self._logger.getEffectiveLevel() > logging.WARNING:
-            self._logger.setLevel(logging.WARNING)
-        # our handler on 'jax' still sees every child record; stopping
-        # propagation there keeps the per-compile WARNINGs off the root
-        # handlers (stderr) while the sentinel is active
-        self._prev_propagate = self._logger.propagate
-        self._logger.propagate = False
-        self._logger.addHandler(self._handler)
+        self._entered = compile_counts()
+        self._exited = None
         return self
 
     def __exit__(self, *exc) -> None:
-        import jax
+        self._exited = compile_counts()
 
-        self._logger.removeHandler(self._handler)
-        self._logger.setLevel(self._prev_level)
-        self._logger.propagate = self._prev_propagate
-        jax.config.update("jax_log_compiles", self._prev_flag)
+    def _now(self) -> dict[str, int]:
+        return compile_counts() if self._exited is None else self._exited
 
     # -- counters ----------------------------------------------------------
 
     @property
     def events(self) -> list[str]:
-        """Kernel names, one per compilation, in order."""
-        with self._handler._lock2:
-            return list(self._handler.events)
+        """Function names, one per compilation, grouped by function."""
+        return _names_since(self._entered, self._now())
 
     @property
     def total(self) -> int:
-        return len(self._handler.events)
+        return len(self.events)
 
-    def snapshot(self) -> int:
-        """Mark the current compile count (call after warm-up)."""
-        return self.total
+    def snapshot(self) -> dict[str, int]:
+        """Mark the current compile counts (call after warm-up)."""
+        return self._now()
 
-    def compiles_since(self, mark: int) -> list[str]:
-        """Names of kernels compiled since ``snapshot()`` — empty means the
-        loop is steady-state clean."""
-        return self.events[mark:]
+    def compiles_since(self, mark: dict[str, int]) -> list[str]:
+        """Names of functions compiled since ``snapshot()`` — empty means
+        the loop is steady-state clean."""
+        return _names_since(mark, self._now())
 
 
 def steady_state_compiles(step_fn, warmup: int = 2, steps: int = 3) -> list[str]:
@@ -124,10 +90,10 @@ def steady_state_compiles(step_fn, warmup: int = 2, steps: int = 3) -> list[str]
 
 def recompile_probe(steps: int = 4) -> dict:
     """The CLI's runtime sentinel check: a warm jit loop must stay at zero
-    compiles. Proves the capture plumbing (jax_log_compiles hook, logger
-    wiring) works in this process — the deep loops (firehose, epoch
-    engine) are sentinel-checked by tests/test_analysis.py and the bench
-    rungs where their compile cost belongs."""
+    compiles. Proves the counting plumbing (the compile listener) works in
+    this process — the deep loops (firehose, epoch engine) are
+    sentinel-checked by tests/test_analysis.py and the bench rungs where
+    their compile cost belongs."""
     import jax
     import jax.numpy as jnp
 

@@ -920,12 +920,16 @@ class BeaconChain:
         retries, and the degradation ladder full device shape -> halved
         batch shape -> pure-Python oracle. A batch whose every rung faults
         fails CLOSED (False -> bisection -> per-group rejection): work may
-        be dropped and counted, but nothing is ever falsely verified."""
+        be dropped and counted, but nothing is ever falsely verified.
+
+        The call is one batch of ``utils.tracing``: the span
+        ``chain.batch_verify`` and every span below it carry its id."""
         if not items:
             return False
         from ..resilience import SupervisedFault
+        from ..utils.tracing import batch_span
 
-        with ATTESTATION_BATCH_VERIFY_TIMES.time():
+        with batch_span("chain.batch_verify"), ATTESTATION_BATCH_VERIFY_TIMES.time():
             try:
                 return self._batch_verify_items_inner(items)
             except SupervisedFault:

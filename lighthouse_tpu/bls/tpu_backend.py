@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from ..ops.bls import curve, fq, g1, g2, pairing, tower
 from ..ops.bls_oracle import curves as _oc
+from ..utils.tracing import VERIFY_PHASE_SECONDS, span
 
 RAND_BITS = 64  # blst.rs:16
 
@@ -164,10 +165,10 @@ def _h2c_stage(n_pad: int):
     from ..ops.bls import h2c
 
     @jax.jit
-    def run(u0, u1):
+    def bls_h2c(u0, u1):
         return g2.to_affine(h2c.map_to_g2(u0, u1))
 
-    return run
+    return bls_h2c
 
 
 @functools.lru_cache(maxsize=None)
@@ -179,7 +180,7 @@ def _prep_stage(n_pad: int, k_pad: int):
     from .serde import raw_to_mont
 
     @jax.jit
-    def run(cache, idx, mask, sxc0, sxc1, s_flag, sig_wf, scalars, valid):
+    def bls_prep(cache, idx, mask, sxc0, sxc1, s_flag, sig_wf, scalars, valid):
         x_mont = raw_to_mont(jnp.stack([sxc0, sxc1], axis=-2))
         sig, on_curve = g2.decompress(x_mont, s_flag)
         pts = cache[idx]                                 # [n, k, 3, 25]
@@ -192,7 +193,7 @@ def _prep_stage(n_pad: int, k_pad: int):
         sax, say = g2.to_affine(sig_acc)
         return pkx, pky, sax, say, set_ok
 
-    return run
+    return bls_prep
 
 
 @functools.lru_cache(maxsize=None)
@@ -200,7 +201,7 @@ def _pair_stage(n_pad: int):
     """Stage 3: batched Miller loops + ONE final exponentiation + verdict."""
 
     @jax.jit
-    def run(pkx, pky, sax, say, mxa, mya, set_ok, valid):
+    def bls_pair(pkx, pky, sax, say, mxa, mya, set_ok, valid):
         px = jnp.concatenate([pkx[:, 0, :], _MG1_X[None]], axis=0)
         py = jnp.concatenate([pky[:, 0, :], _MG1_Y[None]], axis=0)
         qx = jnp.concatenate([mxa, sax[None]], axis=0)
@@ -209,7 +210,7 @@ def _pair_stage(n_pad: int):
         ok = pairing.multi_pairing_is_one(px, py, qx, qy, pair_valid)
         return ok & jnp.all(set_ok) & jnp.any(valid)
 
-    return run
+    return bls_pair
 
 
 def _gathered_kernel(n_pad: int, k_pad: int):
@@ -356,48 +357,56 @@ def verify_indexed_sets_device(cache_arr, items) -> bool:
         return False
     n_pad = bucket(n)
     k_pad = bucket(max((len(ix) for ix, _, _ in items), default=1))
+    timer = VERIFY_PHASE_SECONDS
 
-    idx = np.zeros((n_pad, k_pad), dtype=np.int32)
-    mask = np.zeros((n_pad, k_pad), dtype=bool)
-    sig_bytes = np.zeros((n_pad, 96), dtype=np.uint8)
-    msgs = []
-    for i, (indices, msg, sb) in enumerate(items):
-        k = len(indices)
-        if k > 0:
-            idx[i, :k] = np.asarray(indices, dtype=np.int32)
-            mask[i, :k] = True
-        msgs.append(msg)
-        sig_bytes[i] = np.frombuffer(sb, dtype=np.uint8)
-
-    parsed = parse_g2_bytes(sig_bytes)
-    sig_wf = parsed["wf_ok"] & ~parsed["is_inf"]
-    u0, u1 = h2c.hash_to_field_batch(msgs, DST)
-    if n_pad > n:  # pad by broadcast, not by hashing dummy messages
-        u0 = jnp.concatenate(
-            [u0, jnp.broadcast_to(u0[:1], (n_pad - n,) + u0.shape[1:])]
-        )
-        u1 = jnp.concatenate(
-            [u1, jnp.broadcast_to(u1[:1], (n_pad - n,) + u1.shape[1:])]
-        )
-
-    scalars = np.array(
-        [secrets.randbits(RAND_BITS) or 1 for _ in range(n_pad)], dtype=np.uint64
-    )
-    valid = np.arange(n_pad) < n
-    ok = _gathered_kernel(n_pad, k_pad)(
-        cache_arr,
-        jnp.asarray(idx),
-        jnp.asarray(mask),
-        u0,
-        u1,
-        jnp.asarray(parsed["x_c0"]),
-        jnp.asarray(parsed["x_c1"]),
-        jnp.asarray(parsed["s_flag"]),
-        jnp.asarray(sig_wf),
-        jnp.asarray(scalars),
-        jnp.asarray(valid),
-    )
-    return bool(np.asarray(ok))
+    with span("bls.verify_indexed", n=n, n_pad=n_pad, k_pad=k_pad):
+        with span("bls.host_prep"):
+            with span("bls.pack", timer):
+                idx = np.zeros((n_pad, k_pad), dtype=np.int32)
+                mask = np.zeros((n_pad, k_pad), dtype=bool)
+                sig_bytes = np.zeros((n_pad, 96), dtype=np.uint8)
+                msgs = []
+                for i, (indices, msg, sb) in enumerate(items):
+                    k = len(indices)
+                    if k > 0:
+                        idx[i, :k] = np.asarray(indices, dtype=np.int32)
+                        mask[i, :k] = True
+                    msgs.append(msg)
+                    sig_bytes[i] = np.frombuffer(sb, dtype=np.uint8)
+            with span("bls.parse_signatures", timer):
+                parsed = parse_g2_bytes(sig_bytes)
+                sig_wf = parsed["wf_ok"] & ~parsed["is_inf"]
+            with span("bls.hash_to_field", timer):
+                u0, u1 = h2c.hash_to_field_batch(msgs, DST)
+                if n_pad > n:  # pad by broadcast, not by hashing dummy messages
+                    u0 = jnp.concatenate(
+                        [u0, jnp.broadcast_to(u0[:1], (n_pad - n,) + u0.shape[1:])]
+                    )
+                    u1 = jnp.concatenate(
+                        [u1, jnp.broadcast_to(u1[:1], (n_pad - n,) + u1.shape[1:])]
+                    )
+            with span("bls.scalars", timer):
+                scalars = np.array(
+                    [secrets.randbits(RAND_BITS) or 1 for _ in range(n_pad)],
+                    dtype=np.uint64,
+                )
+                valid = np.arange(n_pad) < n
+        with span("bls.dispatch", timer):
+            ok = _gathered_kernel(n_pad, k_pad)(
+                cache_arr,
+                jnp.asarray(idx),
+                jnp.asarray(mask),
+                u0,
+                u1,
+                jnp.asarray(parsed["x_c0"]),
+                jnp.asarray(parsed["x_c1"]),
+                jnp.asarray(parsed["s_flag"]),
+                jnp.asarray(sig_wf),
+                jnp.asarray(scalars),
+                jnp.asarray(valid),
+            )
+        with span("bls.await_verdict", timer):
+            return bool(np.asarray(ok))
 
 
 @functools.lru_cache(maxsize=None)
@@ -408,11 +417,11 @@ def _sharded_h2c_stage(mesh, n_pad: int):
 
     from ..ops.bls import h2c
 
-    def local(u0, u1):
+    def bls_h2c_sharded(u0, u1):
         return g2.to_affine(h2c.map_to_g2(u0, u1))
 
     return jax.jit(jax.shard_map(
-        local, mesh=mesh, in_specs=(P("sets"),) * 2,
+        bls_h2c_sharded, mesh=mesh, in_specs=(P("sets"),) * 2,
         out_specs=(P("sets"),) * 2,
         check_vma=False,
     ))
@@ -448,8 +457,11 @@ def _sharded_prep_stage(mesh, n_pad: int, k_pad: int):
     per-device G2 signature partial sums + a per-device set_ok verdict."""
     from jax.sharding import PartitionSpec as P
 
+    def bls_prep_sharded(*args):
+        return _local_prep_partials(*args)
+
     return jax.jit(jax.shard_map(
-        _local_prep_partials, mesh=mesh,
+        bls_prep_sharded, mesh=mesh,
         in_specs=(P(),) + (P("sets"),) * 8,
         out_specs=(P("sets"),) * 4,
         check_vma=False,
@@ -461,13 +473,13 @@ def _sharded_array_prologue_stage(mesh, n_pad: int):
     """Sharded twin of ``_prologue_stage`` (pre-aggregated pk/sig arrays)."""
     from jax.sharding import PartitionSpec as P
 
-    def local(pk_agg, sig, scalars, valid):
+    def bls_prologue_sharded(pk_agg, sig, scalars, valid):
         set_ok, pk_scaled, sig_part = _set_prologue(pk_agg, sig, scalars, valid)
         pkx, pky = g1.to_affine(pk_scaled)
         return pkx, pky, sig_part[None], jnp.all(set_ok)[None]
 
     return jax.jit(jax.shard_map(
-        local, mesh=mesh, in_specs=(P("sets"),) * 4,
+        bls_prologue_sharded, mesh=mesh, in_specs=(P("sets"),) * 4,
         out_specs=(P("sets"),) * 4,
         check_vma=False,
     ))
@@ -479,7 +491,7 @@ def _sharded_miller_stage(mesh, n_pad: int):
     product — one [n_dev, 12, 25] partial per device."""
     from jax.sharding import PartitionSpec as P
 
-    def local(pkx, pky, mxa, mya, valid):
+    def bls_miller_sharded(pkx, pky, mxa, mya, valid):
         # backend-dispatched product Miller stage (PR 6): on the digit
         # backend one shared fq12 accumulator covers the device's whole
         # shard; invalid pairs contribute the identity either way
@@ -489,7 +501,7 @@ def _sharded_miller_stage(mesh, n_pad: int):
         return f[None], jnp.any(valid)[None]
 
     return jax.jit(jax.shard_map(
-        local, mesh=mesh, in_specs=(P("sets"),) * 5,
+        bls_miller_sharded, mesh=mesh, in_specs=(P("sets"),) * 5,
         out_specs=(P("sets"),) * 2,
         check_vma=False,
     ))
@@ -504,7 +516,7 @@ def _sharded_combine_stage(mesh):
     and the combined verdict."""
 
     @jax.jit
-    def combine(partial_f, partial_sig, ok_parts, any_parts):
+    def bls_combine_sharded(partial_f, partial_sig, ok_parts, any_parts):
         sig_acc = g2.psum(partial_sig)
         f_all = pairing.fq12_prod(partial_f)
         sx, sy = g2.to_affine(sig_acc)
@@ -513,7 +525,7 @@ def _sharded_combine_stage(mesh):
         ok = tower.fq12_is_one(pairing.final_exponentiation(f))
         return ok & jnp.all(ok_parts) & jnp.any(any_parts)
 
-    return combine
+    return bls_combine_sharded
 
 
 def _sharded_gathered_kernel(mesh, n_pad: int, k_pad: int):
@@ -629,13 +641,13 @@ def _sharded_verdict_stage(mesh, n_pad: int):
     an output gather, no arithmetic collectives)."""
     from jax.sharding import PartitionSpec as P
 
-    def local(pkx, pky, mxa, mya, sig_part, ok_part, valid):
+    def bls_verdict_sharded(pkx, pky, mxa, mya, sig_part, ok_part, valid):
         return _local_pair_verdict(
             pkx, pky, mxa, mya, sig_part[0], ok_part[0], valid
         )[None]
 
     return jax.jit(jax.shard_map(
-        local, mesh=mesh, in_specs=(P("sets"),) * 7, out_specs=P("sets"),
+        bls_verdict_sharded, mesh=mesh, in_specs=(P("sets"),) * 7, out_specs=P("sets"),
         check_vma=False,
     ))
 

@@ -46,6 +46,7 @@ retry/fallback counters in ``utils.metrics``, and ``snapshot()`` for
 
 from __future__ import annotations
 
+import contextvars
 import os
 import random
 import threading
@@ -61,6 +62,7 @@ from ..utils.metrics import (
     RESILIENCE_RETRIES,
     RESILIENCE_WATCHDOG_TIMEOUTS,
 )
+from ..utils.tracing import span
 from . import faults
 from .faults import FaultKind, SupervisedFault, WatchdogTimeout
 from .inject import maybe_fault
@@ -250,9 +252,12 @@ class BackendSupervisor:
         # watchdog workers are deliberately never joined: a wedged device
         # call cannot be killed, so the hang model ABANDONS the thread and
         # counts it against max_hung_threads instead (bounded by the hard
-        # quarantine); done.wait(deadline) is the bounded reclaim
+        # quarantine); done.wait(deadline) is the bounded reclaim. The worker
+        # runs in a copy of the caller's context (the batch id of its spans).
+        ctx = contextvars.copy_context()
         th = threading.Thread(  # lint: allow(unjoined-thread)
-            target=worker, daemon=True, name=f"watchdog-{self.name}-{stage}"
+            target=ctx.run, args=(worker,), daemon=True,
+            name=f"watchdog-{self.name}-{stage}",
         )
         th.start()
         if not done.wait(deadline):
@@ -301,8 +306,9 @@ class BackendSupervisor:
             def guarded():
                 # injection runs INSIDE the watchdog so a hang-mode plan is
                 # detected the way a real wedged call would be
-                maybe_fault(inj_name)
-                return fn()
+                with span("resilience.rung", stage=stage, rung=rung_name):
+                    maybe_fault(inj_name)
+                    return fn()
 
             try:
                 return self._with_watchdog(stage, guarded)
@@ -471,8 +477,10 @@ def run_with_deadline(stage: str, fn, deadline_s: float):
             done.set()
 
     # same abandonment contract as _with_watchdog: the probe thread may be
-    # wedged inside the device client and cannot be joined
-    th = threading.Thread(target=worker, daemon=True, name=f"watchdog-{stage}")  # lint: allow(unjoined-thread)
+    # wedged inside the device client and cannot be joined; it runs in a
+    # copy of the caller's context
+    ctx = contextvars.copy_context()
+    th = threading.Thread(target=ctx.run, args=(worker,), daemon=True, name=f"watchdog-{stage}")  # lint: allow(unjoined-thread)
     th.start()
     if not done.wait(deadline_s):
         raise WatchdogTimeout(stage, deadline_s)

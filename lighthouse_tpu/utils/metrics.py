@@ -91,6 +91,11 @@ class Histogram(_Metric):
             self._sums[key] = self._sums.get(key, 0.0) + value
             self._totals[key] = self._totals.get(key, 0) + 1
 
+    def totals(self) -> dict[tuple, int]:
+        """Observations so far per label tuple."""
+        with self._lock:
+            return dict(self._totals)
+
     @contextmanager
     def time(self, **labels):
         t0 = time.perf_counter()
