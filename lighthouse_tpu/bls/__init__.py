@@ -240,12 +240,11 @@ def _verify_sets_tpu(sets) -> bool:
     # device h2c: host SHA-256 hash_to_field; SSWU/isogeny/cofactor fuse into
     # the verification kernel (one jit) — no oracle pairing-tower hashing and
     # no eager op-by-op dispatch on the hot path
-    u0, u1 = dh2c.hash_to_field_batch([s.message for s in sets], DST)
+    u0, u1 = dh2c.hash_to_field_batch([s.message for s in sets], DST, n_pad)
     if n_pad > n:  # pad by broadcast, not by hashing dummy messages
-        pad = lambda a: jnp.concatenate(
-            [a, jnp.broadcast_to(a[:1], (n_pad - n,) + a.shape[1:])]
+        sig = jnp.concatenate(
+            [sig, jnp.broadcast_to(sig[:1], (n_pad - n,) + sig.shape[1:])]
         )
-        sig, u0, u1 = pad(sig), pad(u0), pad(u1)
     # serving-mesh seam (LIGHTHOUSE_MESH_DEVICES): data-parallel over the
     # device mesh with a cross-device combine; off (the default) keeps the
     # single-device kernel bit-identical to the pre-mesh engine

@@ -377,14 +377,7 @@ def verify_indexed_sets_device(cache_arr, items) -> bool:
                 parsed = parse_g2_bytes(sig_bytes)
                 sig_wf = parsed["wf_ok"] & ~parsed["is_inf"]
             with span("bls.hash_to_field", timer):
-                u0, u1 = h2c.hash_to_field_batch(msgs, DST)
-                if n_pad > n:  # pad by broadcast, not by hashing dummy messages
-                    u0 = jnp.concatenate(
-                        [u0, jnp.broadcast_to(u0[:1], (n_pad - n,) + u0.shape[1:])]
-                    )
-                    u1 = jnp.concatenate(
-                        [u1, jnp.broadcast_to(u1[:1], (n_pad - n,) + u1.shape[1:])]
-                    )
+                u0, u1 = h2c.hash_to_field_host(msgs, DST, n_pad)
             with span("bls.scalars", timer):
                 scalars = np.array(
                     [secrets.randbits(RAND_BITS) or 1 for _ in range(n_pad)],
@@ -396,8 +389,8 @@ def verify_indexed_sets_device(cache_arr, items) -> bool:
                 cache_arr,
                 jnp.asarray(idx),
                 jnp.asarray(mask),
-                u0,
-                u1,
+                jnp.asarray(u0),
+                jnp.asarray(u1),
                 jnp.asarray(parsed["x_c0"]),
                 jnp.asarray(parsed["x_c1"]),
                 jnp.asarray(parsed["s_flag"]),
@@ -584,14 +577,7 @@ def verify_indexed_sets_sharded(cache_arr, items, mesh) -> bool:
 
     parsed = parse_g2_bytes(sig_bytes)
     sig_wf = parsed["wf_ok"] & ~parsed["is_inf"]
-    u0, u1 = h2c.hash_to_field_batch(msgs, DST)
-    if n_pad > n:
-        u0 = jnp.concatenate(
-            [u0, jnp.broadcast_to(u0[:1], (n_pad - n,) + u0.shape[1:])]
-        )
-        u1 = jnp.concatenate(
-            [u1, jnp.broadcast_to(u1[:1], (n_pad - n,) + u1.shape[1:])]
-        )
+    u0, u1 = h2c.hash_to_field_batch(msgs, DST, n_pad)
     scalars = np.array(
         [secrets.randbits(RAND_BITS) or 1 for _ in range(n_pad)], dtype=np.uint64
     )
@@ -697,8 +683,7 @@ def stage_indexed_shards(shard_items, shard_cap: int, k_pad: int | None = None):
     # (masked invalid — they only need to be SOME valid field element)
     u_shape = (n_pad, 2, 25)
     if msgs:
-        ur0, ur1 = h2c.hash_to_field_batch(msgs, DST)
-        ur0, ur1 = np.asarray(ur0), np.asarray(ur1)
+        ur0, ur1 = h2c.hash_to_field_host(msgs, DST)
         u0 = np.broadcast_to(ur0[:1], u_shape).copy()
         u1 = np.broadcast_to(ur1[:1], u_shape).copy()
         u0[rows], u1[rows] = ur0, ur1

@@ -180,14 +180,7 @@ class LcEngine:
 
         parsed = parse_g2_bytes(sig_bytes)
         sig_wf = parsed["wf_ok"] & ~parsed["is_inf"]
-        u0, u1 = h2c.hash_to_field_batch(roots, DST)
-        if n_pad > n:  # pad by broadcast, not by hashing dummy messages
-            u0 = jnp.concatenate(
-                [u0, jnp.broadcast_to(u0[:1], (n_pad - n,) + u0.shape[1:])]
-            )
-            u1 = jnp.concatenate(
-                [u1, jnp.broadcast_to(u1[:1], (n_pad - n,) + u1.shape[1:])]
-            )
+        u0, u1 = h2c.hash_to_field_batch(roots, DST, n_pad)
         scalars = np.array(
             [secrets.randbits(64) or 1 for _ in range(n_pad)], dtype=np.uint64
         )

@@ -1,10 +1,18 @@
 """Hash-to-curve G2 on device (RFC 9380, suite BLS12381G2_XMD:SHA-256_SSWU_RO_).
 
-Split mirrors the suite's structure: ``hash_to_field`` is host-side SHA-256
-(9 hashlib calls per 32-byte message — negligible next to the pairing) that
-yields Fq2 limb arrays; everything algebraic — simplified SWU on the
-3-isogenous curve, the 3-isogeny map, and Budroni–Pintore psi-based cofactor
-clearing — runs branchless on device over the whole message batch at once.
+Split mirrors the suite's structure: ``hash_to_field`` runs on the host
+(9 SHA-256 calls per message) and yields Fq2 limb arrays; everything
+algebraic — simplified SWU on the 3-isogenous curve, the 3-isogeny map, and
+Budroni–Pintore psi-based cofactor clearing — runs branchless on device over
+the whole message batch at once.
+
+The hashing itself is cheap, about 1 ms for 64 messages on a CPU. What
+costs is each array sent to the device: built as one small device array per
+message and Fq2 element, then stacked and padded on the device, the same
+values took about 100 ms of host time and 138 small device programs per
+64-message batch on a TPU v5e host. So ``hash_to_field_host`` builds the
+whole padded batch as two NumPy arrays, and a caller makes one transfer for
+each.
 
 Constants come from the oracle module (``ops.bls_oracle.hash_to_curve``),
 which cross-validates them (h_eff vs psi clearing) in its own tests.
@@ -14,23 +22,70 @@ Parity: blst's hash-or-encode path used by the reference's sign/verify
 
 from __future__ import annotations
 
+import hashlib
+
 import jax.numpy as jnp
+import numpy as np
 
 from . import curve, fq, g2, plans, tower
 from ..bls_oracle import hash_to_curve as _oh
-from ..bls_oracle.fields import BLS_X, Fq2
+from ..bls_oracle.fields import BLS_X, P, Fq2
 
 # -- host: hash_to_field --------------------------------------------------------------
 
+_L = _oh._L                               # bytes per field element (RFC 9380 5.1)
+_LEN_IN_BYTES = 2 * 2 * _L                # two Fq2 elements of two coefficients
+_ELL = _LEN_IN_BYTES // 32                # SHA-256 blocks of expand_message_xmd
+_LIB_STR = _LEN_IN_BYTES.to_bytes(2, "big")
+_Z_PAD = hashlib.sha256(bytes(64))        # the zero block every b_0 starts with
+_LIMB_BYTES = fq.NLIMBS * fq.LIMB_BITS // 8
 
-def hash_to_field_batch(msgs: list[bytes], dst: bytes):
-    """[n messages] -> (u0, u1) device fq2 arrays [n, 2, 25] each."""
-    u0s, u1s = [], []
+
+def _expand_message_xmd(msg: bytes, dst_prime: bytes) -> bytes:
+    """The oracle's expand_message_xmd at ``_LEN_IN_BYTES``, with the zero
+    block hashed once and b_0 ^ b_(i-1) taken on ints, not byte by byte."""
+    h = _Z_PAD.copy()
+    h.update(msg + _LIB_STR + b"\x00" + dst_prime)
+    b0 = h.digest()
+    b0_int = int.from_bytes(b0, "big")
+    bi = hashlib.sha256(b0 + b"\x01" + dst_prime).digest()
+    out = [bi]
+    for i in range(2, _ELL + 1):
+        x = (b0_int ^ int.from_bytes(bi, "big")).to_bytes(32, "big")
+        bi = hashlib.sha256(x + bytes((i,)) + dst_prime).digest()
+        out.append(bi)
+    return b"".join(out)
+
+
+def hash_to_field_host(msgs: list[bytes], dst: bytes, n_pad: int | None = None):
+    """[n messages] -> (u0, u1), NumPy uint64 [n_pad, 2, 25] each: the
+    oracle's ``hash_to_field_fq2(m, dst, 2)`` as canonical 16-bit limbs
+    (every value < P, which ``map_to_curve_sswu_fraction`` relies on).
+    Rows n..n_pad-1 repeat row 0. Creates no JAX array."""
+    n = len(msgs)
+    n_pad = n if n_pad is None else n_pad
+    if n == 0 or n_pad < n:
+        raise ValueError(f"hash_to_field_host: {n} messages, n_pad {n_pad}")
+    if len(dst) > 255:
+        raise ValueError("expand_message_xmd bounds")
+    dst_prime = dst + bytes((len(dst),))
+    parts = []
     for m in msgs:
-        u0, u1 = _oh.hash_to_field_fq2(m, dst, 2)
-        u0s.append(tower.from_ints([u0.c0, u0.c1]))
-        u1s.append(tower.from_ints([u1.c0, u1.c1]))
-    return jnp.stack(u0s), jnp.stack(u1s)
+        uniform = _expand_message_xmd(m, dst_prime)
+        for off in range(0, _LEN_IN_BYTES, _L):
+            e = int.from_bytes(uniform[off : off + _L], "big") % P
+            parts.append(e.to_bytes(_LIMB_BYTES, "little"))
+    u = np.empty((n_pad, 2, 2, fq.NLIMBS), dtype=np.uint64)
+    u[:n] = np.frombuffer(b"".join(parts), "<u2").reshape(n, 2, 2, fq.NLIMBS)
+    u[n:] = u[:1]
+    u0, u1 = np.ascontiguousarray(np.moveaxis(u, 1, 0))
+    return u0, u1
+
+
+def hash_to_field_batch(msgs: list[bytes], dst: bytes, n_pad: int | None = None):
+    """``hash_to_field_host`` as device arrays: one transfer for each."""
+    u0, u1 = hash_to_field_host(msgs, dst, n_pad)
+    return jnp.asarray(u0), jnp.asarray(u1)
 
 
 # -- device constants -----------------------------------------------------------------

@@ -26,7 +26,8 @@ trace, ``benchmark/programtrace.py``):
           bls.host_prep
             bls.pack              index / mask / signature byte arrays
             bls.parse_signatures  parse_g2_bytes
-            bls.hash_to_field     hash_to_field_batch and its pad
+            bls.hash_to_field     hash_to_field_host: the padded batch's
+                                  u0, u1 as host arrays
             bls.scalars           the random linear-combination scalars
           bls.dispatch            host->device transfers, the three stage
                                   calls as they are enqueued
