@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from ..ops.bls import curve, fq, g1, g2, pairing, tower
 from ..ops.bls_oracle import curves as _oc
+from ..utils.stage_store import StoredStage
 from ..utils.tracing import VERIFY_PHASE_SECONDS, span
 
 RAND_BITS = 64  # blst.rs:16
@@ -157,7 +158,24 @@ def aggregate_pubkeys_device(pts: list, k_pad: int | None = None):
     return _aggregate_kernel(k_pad)(buf, jnp.asarray(mask))
 
 
-@functools.lru_cache(maxsize=None)
+def _stored(factory):
+    """A cached stage factory whose stages come from the stage store
+    (``utils/stage_store.py``): loaded where a process before stored them
+    at the same shapes, else traced and stored. ``__wrapped__`` stays the
+    factory as written, a fresh jit at each call that never reads or
+    writes the store."""
+
+    @functools.lru_cache(maxsize=None)
+    def stage(*shape):
+        # the conv backend decides the trace besides the source, the
+        # platform and the LIGHTHOUSE_* environment (all in the store's
+        # key); it is fixed at its first use
+        return StoredStage(factory(*shape), (("conv", fq.conv_backend()),))
+
+    return functools.update_wrapper(stage, factory)
+
+
+@_stored
 def _h2c_stage(n_pad: int):
     """Stage 1 of the chain hot path: device SSWU + isogeny + cofactor
     clearing + affine conversion for the message points. Shape depends only
@@ -171,7 +189,7 @@ def _h2c_stage(n_pad: int):
     return bls_h2c
 
 
-@functools.lru_cache(maxsize=None)
+@_stored
 def _prep_stage(n_pad: int, k_pad: int):
     """Stage 2: signature decompression + cache gather + masked aggregation +
     the security prologue (subgroup checks, random scaling, signature sum),
@@ -196,7 +214,7 @@ def _prep_stage(n_pad: int, k_pad: int):
     return bls_prep
 
 
-@functools.lru_cache(maxsize=None)
+@_stored
 def _pair_stage(n_pad: int):
     """Stage 3: batched Miller loops + ONE final exponentiation + verdict."""
 

@@ -42,8 +42,15 @@ The compile listener, installed when ``lighthouse_tpu`` is imported, feeds
 backend compile, or the load on a persistent-cache hit). ``fun`` is the
 event's function name. JAX reports a trace event for every jitted function
 traced inside another one; those nested events are part of the outermost
-trace's time and are not observed again, so the histogram's sum is the
-time the process spent in the three phases.
+trace's time and are not observed again. A fourth phase, ``load``, is the
+stage store's own (``utils/stage_store.py``): reading, writing and
+(de)serializing a stored stage, and tracing the thin call around it, which
+``compile_phase`` times under the stage's name. So the histogram's sum is
+the time the process spent in the compile layer.
+
+``bls_stage_store_total{stage, outcome}`` counts how each stored stage was
+resolved in this process: ``hit`` (loaded, never traced), ``miss`` (traced
+and stored) or ``invalid`` (a bad entry, deleted, traced and stored again).
 """
 
 from __future__ import annotations
@@ -66,10 +73,17 @@ VERIFY_PHASE_SECONDS = REGISTRY.histogram(
 )
 COMPILE_PHASE_SECONDS = REGISTRY.histogram(
     "jax_compile_phase_seconds",
-    "JAX tracing, lowering and backend compile (or persistent-cache load) "
-    "time per jitted function",
+    "JAX tracing, lowering and backend compile (or persistent-cache load), "
+    "and stage-store loads, time per jitted function",
     label_names=("phase", "fun"),
     buckets=(0.01, 0.1, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0, 120.0, 300.0),
+)
+
+STAGE_STORE_TOTAL = REGISTRY.counter(
+    "bls_stage_store_total",
+    "Device stages resolved from the stage store, by outcome "
+    "(hit / miss / invalid; utils/stage_store.py)",
+    label_names=("stage", "outcome"),
 )
 
 _BATCH: contextvars.ContextVar[int] = contextvars.ContextVar("lighthouse_tpu_batch")
@@ -135,6 +149,21 @@ def _on_duration(event: str, seconds: float, **kw) -> None:
 
 jax.monitoring.register_scalar_listener(_on_enter)
 jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+@contextmanager
+def compile_phase(phase: str, fun: str):
+    """Observe the block's duration as ``jax_compile_phase_seconds{phase,
+    fun}``; a JAX trace inside it is part of that time and is not observed
+    on its own."""
+    _trace_depth.n = getattr(_trace_depth, "n", 0) + 1
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        _trace_depth.n -= 1
+        COMPILE_PHASE_SECONDS.observe(time.perf_counter() - t0, phase=phase,
+                                      fun=fun)
 
 
 def compile_counts() -> dict[str, int]:

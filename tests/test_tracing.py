@@ -198,6 +198,23 @@ def test_nested_traces_are_not_counted_twice():
     assert new[("compile", "jit(outer_probe)")] == 1
 
 
+def test_compile_phase_holds_the_traces_inside_it():
+    @jax.jit
+    def phase_probe(x):
+        return x * 5
+
+    before = tracing.COMPILE_PHASE_SECONDS.totals()
+    with tracing.compile_phase("load", "phase_probe"):
+        traced = phase_probe.trace(np.ones(3, np.float32))
+    traced.lower().compile()
+    after = tracing.COMPILE_PHASE_SECONDS.totals()
+    new = {k: n - before.get(k, 0) for k, n in after.items()
+           if n != before.get(k, 0)}
+    assert new == {("load", "phase_probe"): 1,
+                   ("lower", "jit(phase_probe)"): 1,
+                   ("compile", "jit(phase_probe)"): 1}
+
+
 _VAR = contextvars.ContextVar("test_tracing_var")
 
 
