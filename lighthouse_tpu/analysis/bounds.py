@@ -218,6 +218,10 @@ def graph_registry(batch: int) -> list[tuple]:
         ("curve.point_dbl.g1", g(1, curve.point_dbl), (p1,)),
         ("curve.point_add.g2", g(2, curve.point_add), (p2, p2)),
         ("curve.point_dbl.g2", g(2, curve.point_dbl), (p2,)),
+        # an aggregation level at 1,024 x batch rows: under the pallas
+        # regime its kernels take the digit-major layout
+        ("curve.point_sum_g1_wide", g(1, curve.point_sum),
+         (jax.ShapeDtypeStruct((2, 1024) + B + (3, 25), u64),)),
         ("curve.point_eq.g2", g(2, curve.point_eq), (p2, p2)),
         ("curve.to_affine.g2", g(2, curve.to_affine), (p2,)),
         ("curve.scale_fixed_x.g2",

@@ -15,8 +15,8 @@ attempt. This module makes residency the sixth certified pass:
   lowered-computation cost analysis cross-checks a representative subset —
   ``LIGHTHOUSE_MEMORY_XLA=full`` extends it to every row).
 * **VMEM tile walk** — under the pallas regime every fused-kernel launch
-  records its tile signature (operand blocks, the lane scratch, constant
-  pools) through ``pallas_kernels._VMEM_SINK``;
+  records its layout and tile signature (operand blocks, the lane or
+  digit-plane scratch, constant pools) through ``pallas_kernels._VMEM_SINK``;
   each distinct signature is checked against the declared per-tier VMEM
   caps.
 * **Subsystem residency models** — one static ``*_bytes(config)`` function
@@ -418,7 +418,8 @@ def _trace_footprint(name: str, fn, specs, tiers: dict) -> list[dict]:
     rows = [row]
     seen = set()
     for rec in vmem_records:
-        key = (rec["tile"], rec["lanes"], rec["n_rows_out"], rec["n_pass"])
+        key = (rec["layout"], rec["tile"], rec["lanes"], rec["n_rows_out"],
+               rec["n_pass"])
         if key in seen:
             continue
         seen.add(key)

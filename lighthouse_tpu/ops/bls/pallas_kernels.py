@@ -14,27 +14,35 @@ memory per call. Here the WHOLE pipeline after the input lincombs runs as ONE
   intermediate is proven < 2^24, the f32 integer-exactness cap, so the whole
   kernel is EXACT integer arithmetic in float registers.
 
-* **Lane planes.** Every in-kernel array is a 2-D ``[8, 128]`` f32 plane —
-  one vreg: digit position d of a row sits in lane d. Lanes of a tower op
-  ride in a leading axis (``[lanes, rows, 128]`` blocks) and the body walks
-  the block 8 rows at a time, so Mosaic sees only lane rotations, lane
-  broadcasts and leading-axis ref indexing (its layout inference refuses,
-  e.g., a 4-D digit outer product reshaped to 2-D).
+* **Two block layouts, chosen by the call's rows** (``layout_for``).
 
-* **Convolution as a shifted-FMA chain.** ``t += A[:, i] * roll(B, i)`` for
-  the 51 digits of A: a lane broadcast, a lane rotation and an FMA per step
-  on the VPU. The 101 conv positions stay below lane 128, so no rotation
-  wraps a non-zero digit.
+  - *Lane* (below ``DIGIT_MAJOR_MIN_ROWS``): every in-kernel array is a
+    2-D ``[8, 128]`` f32 plane — one vreg: digit position d of a row sits
+    in lane d. Lanes of a tower op ride in a leading axis (``[lanes, rows,
+    128]`` blocks) and the body walks the block 8 rows at a time, so Mosaic
+    sees only lane rotations, lane broadcasts and leading-axis ref indexing
+    (its layout inference refuses, e.g., a 4-D digit outer product reshaped
+    to 2-D). The conv is a shifted-FMA chain, ``t += A[:, i] * roll(B,
+    i)``: a lane broadcast, a lane rotation and an FMA per step; the 101
+    conv positions stay below lane 128, so no rotation wraps a non-zero
+    digit.
+  - *Digit-major* (from ``DIGIT_MAJOR_MIN_ROWS`` up): operands are ``[lanes,
+    51, rows / 128, 128]``, so one vreg holds one digit of 1,024 rows and
+    every schedule step is elementwise across digit planes: the conv is
+    multiply-adds of whole planes, the fold multiplies by rows of an F8
+    table, a carry moves one plane into the next. No op crosses lanes.
 
 * **Congruence fold.** Digit positions >= 48 (weight 2^384) fold through
-  constant rows F8[h] = digits(2^(8*(48+h)) mod p) as broadcast-FMA terms.
+  constant rows F8[h] = digits(2^(8*(48+h)) mod p).
 
 * **The out-lincomb rides inside too** (``execute_plan``): a tower op's
-  output linear map runs on the unreduced conv digits as one
-  sum of lane planes with the integer coefficients unrolled into the body
-  (negative coefficients via digit-space borrow constants == 0 mod p), so an
-  fq12 multiply still reduces 12 rows, not 54 lanes — the plans.py
-  contract, fused.
+  output linear map runs on the unreduced conv digits with the integer
+  coefficients of the plan (negative coefficients via digit-space borrow
+  constants == 0 mod p), so an fq12 multiply still reduces 12 rows, not 54
+  lanes — the plans.py contract, fused.
+
+Both layouts replay the same static schedule on the same exact integers, so
+their outputs agree bit for bit.
 
 Every bound the schedule relies on is recorded as a trace-time ``fq._cert``
 obligation (kinds ``pallas_*``) and proven per-graph by
@@ -61,6 +69,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import fq
+from ...utils.tracing import PALLAS_KERNELS_TOTAL
 from ..bls_oracle.fields import P
 
 _D = 51                 # digits per 25-limb element (base 2^8; fq._N_DIGITS)
@@ -129,6 +138,7 @@ def _record_vmem(tile: int, L: int, rows_p: int, out_key) -> None:
     # chunk wait in a [L, 8, 128] scratch for the out-lincomb
     work = L * _CH * _W * 4
     _VMEM_SINK.append({
+        "layout": "lane",
         "tile": tile,
         "lanes": L,
         "grid": rows_p // tile,
@@ -139,6 +149,29 @@ def _record_vmem(tile: int, L: int, rows_p: int, out_key) -> None:
         "scratch_bytes": work,
         # grid-blocked operands double-buffer across grid steps
         "est_vmem_bytes": work + 2 * (blocks_in + block_out) + const_b,
+    })
+
+
+def _record_dm_vmem(sub, L, rows_p, pre_ops, out_key, post_ops) -> None:
+    """The digit-major twin of _record_vmem: one grid step holds one lane's
+    operand blocks, the out-rows' block, the F8 table and the conv /
+    accumulator scratch."""
+    if _VMEM_SINK is None:
+        return
+    wt, wa = _dm_scratch_widths(pre_ops, out_key, post_ops)
+    if out_key is not None:
+        R, _, _, _, n_pass = _OUT_TABLE[out_key]
+    else:
+        R, n_pass = L, 0
+    est = _dm_vmem(sub, R, n_pass, wt, wa)
+    _VMEM_SINK.append({
+        "layout": "digit_major",
+        "tile": sub * _W,
+        "lanes": L,
+        "grid": rows_p // (sub * _W) * L,
+        "n_rows_out": R,
+        "n_pass": n_pass,
+        **est,
     })
 
 
@@ -207,7 +240,7 @@ def _trim_state(s: _DState) -> _DState:
 
 
 def _reduce_schedule(
-    s: _DState, value_limit: int, limb_target: int, name: str
+    s: _DState, value_limit: int, limb_target: int, name: str, layout: str
 ) -> tuple[list, _DState]:
     """Static split/fold schedule bringing the state to value <= value_limit
     and recombined 16-bit limbs <= limb_target — the digit-domain twin of
@@ -234,7 +267,7 @@ def _reduce_schedule(
         # already hold zeros
         s = _trim_state(s)
         w = len(s.digits)
-        _lane_cert(w, name)
+        _width_cert(w, name, layout)
         if w > _OUT_D or (s.value > value_limit and w > _FOLD_BASE):
             if _fold_budget(s) <= _F32_CAP:
                 s = _fold_state(s, name)
@@ -336,13 +369,19 @@ def _row_tile(rows: int, lanes: int) -> int:
     return min(tile, max(_CH, -(-rows // _CH) * _CH))
 
 
-def _lane_cert(width: int, name: str) -> None:
-    """A plane of ``width`` digit positions must fit the 128-lane register
-    row with its top lane free: a carry-save split rolls every lane up one,
-    and lane 127 wraps to lane 0."""
-    assert fq._cert(
-        "pallas_lane_width", width, _W - 1, note=name
-    ), f"{name}: pallas digit plane exceeds {_W - 1} lanes"
+def _width_cert(width: int, name: str, layout: str) -> None:
+    """A state of ``width`` digit positions must fit its layout. Lane: the
+    128-lane register row with its top lane free (a carry-save split rolls
+    every lane up one, and lane 127 wraps to lane 0). Digit-major: each
+    plane a fold reads must have its F8 row (the table holds _N_FOLD8)."""
+    if layout == "lane":
+        assert fq._cert(
+            "pallas_lane_width", width, _W - 1, note=name
+        ), f"{name}: pallas digit plane exceeds {_W - 1} lanes"
+    else:
+        assert fq._cert(
+            "pallas_digit_planes", width, _FOLD_BASE + _N_FOLD8, note=name
+        ), f"{name}: pallas digit-major width exceeds the F8 rows"
 
 
 def _roll(t, k: int):
@@ -514,6 +553,308 @@ def _build_call(
     return run
 
 
+# --------------------------------------------------------------------------------------
+# Digit-major layout: rows across the vreg, digit positions on a leading axis
+# --------------------------------------------------------------------------------------
+#
+# Operands are [L, D, rows / 128, 128] f32: plane d of a block holds digit d
+# of S x 128 rows, so one [8, 128] vreg carries one digit of 1,024 rows and
+# every step of the schedule is elementwise across planes. The conv is plain
+# multiply-adds of whole planes, the fold multiplies the 48 low planes by
+# rows of an F8 table held in VMEM, a split carries one plane into the next;
+# nothing crosses lanes. The grid walks (row block, lane); a plan's out-rows
+# accumulate over the lane axis in VMEM and are reduced at the last lane.
+# Results are the lane kernel's, bit for bit: the schedule and its bounds
+# are the same, and every intermediate is an exact integer below 2^24, so
+# the order of the sums does not matter.
+#
+# Measured on a TPU v5e (PERF.md): the F8 entries as constants in the code
+# ran the kernel 6-71% faster than the table, but made it 5x larger to
+# trace, lower and compile (6,659 equations against 1,266 for fused_mul),
+# which every process that traces kernels pays at start; as SMEM scalars
+# the first fold was 7x slower. Register blocks of 8 to 17 conv outputs
+# time the same.
+
+_DM_S = 8         # sublanes of a plane in a full block: 1,024 rows a grid step
+_DM_KB = 13       # conv outputs accumulated in registers at once
+# the F8 rows for the digit-major fold: entry [h, j] across the 128 lanes
+_FOLD8_DM = np.ascontiguousarray(np.broadcast_to(
+    _FOLD8_NP[:, :_FOLD_BASE, None, None], (_N_FOLD8, _FOLD_BASE, 1, _W)
+))
+
+
+def _dm_rows(rows: int) -> tuple[int, int]:
+    """(sublanes S of a block, padded row count) for the digit-major layout:
+    S = 8 (1,024 rows a grid step) from 1,024 rows up; below that one block
+    of the next power of two of 128-row sublanes."""
+    ns = -(-rows // _W)
+    s = _DM_S if ns >= _DM_S else 1 << (ns - 1).bit_length()
+    return s, -(-ns // s) * s * _W
+
+
+def _dm_widths(w: int, ops) -> tuple[int, int]:
+    """(final width, widest plane count) of a schedule replayed on w planes:
+    a split adds the top plane, a fold leaves the 48 low ones."""
+    top = w
+    for op in ops:
+        if op[0] == "split":
+            w += 1
+        else:
+            assert _FOLD_BASE + op[1] <= w
+            w = _FOLD_BASE
+        top = max(top, w)
+    return w, top
+
+
+def _dm_scratch_widths(pre_ops, out_key, post_ops) -> tuple[int, int]:
+    """Planes of the conv scratch and of each out-row accumulator (0
+    without an out-map) that a schedule needs."""
+    w_conv, wt = _dm_widths(_CONV_D, pre_ops)
+    wa = 0 if out_key is None else _dm_widths(w_conv, post_ops)[1]
+    return wt, wa
+
+
+def _dm_conv(a, b, t) -> None:
+    """t[d] = sum_i a[i] * b[d - i] over the 51 x 51 digit planes of the
+    refs a and b into planes 0..100 of t, _DM_KB outputs at a time: a
+    block's accumulators and a window of b planes ride in registers
+    through a loop over i, which loads one a plane and shifts in one b
+    plane a step (zeros once past b's first digit). Exact like the lane
+    conv (pallas_conv_digit_f32_exact)."""
+    top = _D - 1
+    for d0 in range(0, _CONV_D, _DM_KB):
+        k_n = min(_DM_KB, _CONV_D - d0)
+        lo, hi = max(0, d0 - top), min(top, d0 + k_n - 1)
+        # zero planes laid out like loaded ones: Mosaic keeps a loop carry
+        # in one layout, and a constant's is replicated
+        zero = a[0] * 0.0
+        # window[k] = b[d0 + k - i]
+        win = tuple(b[d0 + k - lo] if d0 + k - lo <= top else zero
+                    for k in range(k_n))
+
+        def step(i, carry, d0=d0, shift_in=True):
+            accs, win = carry
+            ai = a[i]
+            accs = tuple(
+                jax.lax.add(acc, jax.lax.mul(ai, bk)) for acc, bk in zip(accs, win)
+            )
+            nb = b[d0 - i - 1] if shift_in else ai * 0.0
+            return accs, (nb,) + win[:-1]
+
+        carry = ((zero,) * k_n, win)
+        mid = min(hi + 1, d0)  # b[d0 - i - 1] exists for i < d0
+        if mid > lo:
+            carry = jax.lax.fori_loop(lo, mid, step, carry)
+        if hi + 1 > max(lo, mid):
+            carry = jax.lax.fori_loop(
+                max(lo, mid), hi + 1,
+                functools.partial(step, d0=d0, shift_in=False), carry,
+            )
+        t[d0 : d0 + k_n] = jnp.stack(carry[0])
+
+
+def _dm_split(t, w: int) -> None:
+    """Carry-save round over planes 0..w of t: d -> (d mod 256) + (d_{-1}
+    div 256), width + 1 (the digit-major twin of _split_array), as one
+    array op over the planes."""
+    x = t[0:w]
+    hi = jnp.floor(x * (1.0 / 256.0))
+    zero = jnp.zeros((1,) + x.shape[1:], jnp.float32)
+    t[0 : w + 1] = jnp.concatenate([x - hi * 256.0, zero]) + jnp.concatenate(
+        [zero, hi]
+    )
+
+
+def _dm_fold(t, n_hi: int, f8) -> None:
+    """Fold planes 48..48+n_hi of t into the 48 low planes through the F8
+    rows: one array op over the 48 planes for each folded plane, its F8
+    row read from ``f8`` ([64, 48, 1, 128], entry [h, j] across the
+    lanes)."""
+    lo = t[0:_FOLD_BASE]
+    hi = t[_FOLD_BASE : _FOLD_BASE + n_hi]
+    for h in range(n_hi):
+        lo = jax.lax.add(lo, jax.lax.mul(hi[h][None], f8[h]))
+    t[0:_FOLD_BASE] = lo
+
+
+def _dm_replay(t, w: int, ops, f8) -> int:
+    """Apply a static reduce schedule to the first w planes of t in place;
+    returns the final width (planes at and above it are dead)."""
+    for op in ops:
+        if op[0] == "split":
+            _dm_split(t, w)
+            w += 1
+        else:
+            _dm_fold(t, op[1], f8)
+            w = _FOLD_BASE
+    return w
+
+
+def _dm_write(o, t, w: int) -> None:
+    """The 50 output planes: t's first w, zeros above."""
+    n = min(w, _OUT_D)
+    o[0:n] = t[0:n]
+    if n < _OUT_D:
+        o[n:_OUT_D] = jnp.zeros((_OUT_D - n,) + o.shape[1:], jnp.float32)
+
+
+@functools.lru_cache(maxsize=512)
+def _build_dm_call(
+    rows_p: int,
+    sub: int,
+    n_lanes: int,
+    pre_ops: tuple,
+    out_key,
+    post_ops: tuple,
+    interpret: bool,
+):
+    """Build (and cache) the digit-major pallas_call for one signature.
+
+    Operands are digit planes ``[lanes, D, rows_p / 128, 128]``; the grid is
+    (row block, lane), a block holds ``sub`` x 128 rows of one lane. Without
+    an out-map each lane's reduced planes are its output; with one, the
+    lanes' pre-split conv planes accumulate into the out-rows (borrow
+    constants and pass-through terms seeded at the first lane) and the
+    out-rows are reduced and written at the last lane."""
+    L = n_lanes
+    grid = (rows_p // (sub * _W), L)
+    plane = (sub, _W)
+    wt, wa = _dm_scratch_widths(pre_ops, out_key, post_ops)
+    has_out = out_key is not None
+    if has_out:
+        R, mpos_np, mneg_np, oconst_np, n_pass = _OUT_TABLE[out_key]
+        # signed out-map coefficients of the lanes and of the pass-through
+        # inputs, read as SMEM scalars
+        coef_np = (mpos_np - mneg_np).astype(np.float32)
+    else:
+        R, n_pass = L, 0
+
+    def body(*refs):
+        a_ref, b_ref, f8_ref = refs[:3]
+        rest = list(refs[3:])
+        if has_out:
+            coef_ref, oconst_ref = rest.pop(0), rest.pop(0)
+        ain_ref = rest.pop(0) if n_pass else None
+        o_ref, t_ref = rest[0], rest[1]
+
+        _dm_conv(a_ref.at[0], b_ref.at[0], t_ref)
+        w = _dm_replay(t_ref, _CONV_D, pre_ops, f8_ref)
+
+        if not has_out:
+            _dm_write(o_ref.at[0], t_ref, w)
+            return
+
+        acc_ref = rest[2]
+        lane = pl.program_id(1)
+
+        def rows_loop(fn):
+            def step(r, c):
+                fn(r)
+                return c
+
+            jax.lax.fori_loop(0, R, step, 0)
+
+        def accumulate(r, c, src, n):
+            @pl.when(c != 0.0)
+            def _():
+                acc_ref[r, 0:n] = acc_ref[r, 0:n] + src[0:n] * c  # lint: allow(impure-closure) — ref store
+
+        @pl.when(lane == 0)
+        def _seed():
+            # borrow constants (zero for rows without a negative term), then
+            # the pass-through terms of the raw input
+            def seed(r):
+                acc_ref[r, 0:w] = jnp.stack(  # lint: allow(impure-closure) — ref store
+                    [jnp.full(plane, oconst_ref[r, d], jnp.float32) for d in range(w)]
+                )
+                for j in range(n_pass):
+                    accumulate(r, coef_ref[r, L + j], ain_ref.at[j], _D)
+
+            rows_loop(seed)
+
+        rows_loop(lambda r: accumulate(r, coef_ref[r, lane], t_ref, w))
+
+        @pl.when(lane == L - 1)
+        def _reduce():
+            def reduce_row(r):
+                row = acc_ref.at[r]
+                _dm_write(o_ref.at[r], row, _dm_replay(row, w, post_ops, f8_ref))
+
+            rows_loop(reduce_row)
+
+    def lane_bs(n_dig):
+        return pl.BlockSpec((1, n_dig, sub, _W), lambda i, l: (l, 0, i, 0))
+
+    def smem():
+        return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+    in_specs = [
+        lane_bs(_D), lane_bs(_D),
+        pl.BlockSpec(_FOLD8_DM.shape, lambda i, l: (0, 0, 0, 0)),
+    ]
+    operands_const = [_FOLD8_DM]
+    scratch = [pltpu.VMEM((wt, sub, _W), jnp.float32)]
+    if has_out:
+        in_specs += [smem(), smem()]
+        operands_const += [coef_np, oconst_np]
+        scratch.append(pltpu.VMEM((R, wa, sub, _W), jnp.float32))
+        out_spec = pl.BlockSpec((R, _OUT_D, sub, _W), lambda i, l: (0, 0, i, 0))
+    else:
+        out_spec = lane_bs(_OUT_D)
+    if n_pass:
+        in_specs.append(
+            pl.BlockSpec((n_pass, _D, sub, _W), lambda i, l: (0, 0, i, 0))
+        )
+
+    call = pl.pallas_call(
+        body,
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_spec,
+        out_shape=jax.ShapeDtypeStruct(
+            (R, _OUT_D, rows_p // _W, _W), jnp.float32
+        ),
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_dm_vmem_limit(_dm_vmem(sub, R, n_pass, wt, wa)),
+        ),
+        interpret=interpret,
+        name="fused_digit_major",
+    )
+
+    def run(A_d, B_d, Ain_d=None):
+        args = [A_d, B_d] + [jnp.asarray(c) for c in operands_const]
+        if n_pass:
+            args.append(Ain_d)
+        with jax.enable_x64(False), jax.named_scope("fused_digit_major"):
+            return call(*args)
+
+    return run
+
+
+def _dm_vmem(sub, R, n_pass, wt, wa) -> dict:
+    """VMEM working set of one digit-major grid step, in bytes."""
+    plane = sub * _W * 4
+    blocks_in = (2 * _D + n_pass * _D) * plane
+    block_out = R * _OUT_D * plane if wa else _OUT_D * plane
+    scratch = (wt + R * wa) * plane
+    const = _FOLD8_DM.nbytes
+    return {
+        "block_bytes": blocks_in + block_out,
+        "const_bytes": const,
+        "scratch_bytes": scratch,
+        # every blocked operand, the F8 table too, double-buffers
+        "est_vmem_bytes": scratch + 2 * (blocks_in + block_out + const),
+    }
+
+
+def _dm_vmem_limit(est: dict) -> int:
+    """Scoped VMEM to ask Mosaic for: the working set plus room for its
+    own temporaries, at least its 16 MiB default."""
+    return max(16 << 20, est["est_vmem_bytes"] + (8 << 20))
+
+
 # side table: content-addressed out-map matrices (lru_cache keys must be
 # hashable; the key is a digest of the matrix content, the table holds the
 # arrays themselves)
@@ -554,19 +895,54 @@ def _rows_of(shape) -> int:
     return n
 
 
+def _dm_planes(x, n_lanes: int, rows_p: int):
+    """u64 limbs [..., n_lanes, 25] -> f32 digit planes [n_lanes, 51,
+    rows_p / 128, 128]: digit d of row 128 s + j at [.., d, s, j]."""
+    d = fq._to_digits_f32(x.astype(jnp.uint64)).reshape(-1, n_lanes, _D)
+    d = jnp.transpose(d, (1, 2, 0))
+    d = jnp.pad(d, ((0, 0), (0, 0), (0, rows_p - d.shape[2])))
+    return d.reshape(n_lanes, _D, rows_p // _W, _W)
+
+
+# Calls of at least this many rows run the digit-major kernel, the others
+# the lane kernel: the crossover measured on a TPU v5e (PERF.md, the sweep
+# of both layouts by rows and lanes).
+DIGIT_MAJOR_MIN_ROWS = 64
+
+
+def layout_for(rows: int) -> str:
+    """The kernel layout a call of ``rows`` rows runs in."""
+    return "digit_major" if rows >= DIGIT_MAJOR_MIN_ROWS else "lane"
+
+
 def _run_fused(A, B, pre_ops, out_key, post_ops, Ain=None):
     """Run the cached call on u64 operands [rows, L, 25] (Ain: [rows,
-    n_pass, 25]); returns limbs [rows, n_rows_out, 25]."""
+    n_pass, 25]) in the layout its rows choose; returns limbs [rows,
+    n_rows_out, 25]."""
     rows, L = A.shape[0], A.shape[1]
-    tile = _row_tile(rows, L)
-    rows_p = -(-rows // tile) * tile
-    _record_vmem(tile, L, rows_p, out_key)
-    run = _build_call(
-        rows_p, tile, L, tuple(pre_ops), out_key, tuple(post_ops), _interpret()
+    layout = layout_for(rows)
+    PALLAS_KERNELS_TOTAL.inc(layout=layout)
+    n_pass = 0 if Ain is None else Ain.shape[1]
+    if layout == "lane":
+        tile = _row_tile(rows, L)
+        rows_p = -(-rows // tile) * tile
+        _record_vmem(tile, L, rows_p, out_key)
+        run = _build_call(
+            rows_p, tile, L, tuple(pre_ops), out_key, tuple(post_ops),
+            _interpret(),
+        )
+        ain = None if Ain is None else _planes(Ain, n_pass, rows_p)
+        out = run(_planes(A, L, rows_p), _planes(B, L, rows_p), ain)
+        return _limbs_of(jnp.moveaxis(out[:, :rows, :_OUT_D], 0, 1))
+    sub, rows_p = _dm_rows(rows)
+    _record_dm_vmem(sub, L, rows_p, tuple(pre_ops), out_key, tuple(post_ops))
+    run = _build_dm_call(
+        rows_p, sub, L, tuple(pre_ops), out_key, tuple(post_ops), _interpret()
     )
-    ain = None if Ain is None else _planes(Ain, Ain.shape[1], rows_p)
-    out = run(_planes(A, L, rows_p), _planes(B, L, rows_p), ain)
-    return _limbs_of(jnp.moveaxis(out[:, :rows, :_OUT_D], 0, 1))
+    ain = None if Ain is None else _dm_planes(Ain, n_pass, rows_p)
+    out = run(_dm_planes(A, L, rows_p), _dm_planes(B, L, rows_p), ain)
+    out = out.reshape(out.shape[0], _OUT_D, rows_p)[:, :, :rows]
+    return _limbs_of(jnp.transpose(out, (2, 0, 1)))
 
 
 def fused_mul(a, b, lazy: bool = False):
@@ -594,7 +970,9 @@ def fused_mul(a, b, lazy: bool = False):
         "pallas_conv_digit_f32_exact", max(conv), _F32_CAP, note=name
     ), f"{name}: digit conv exceeds f32 exactness"
     state = _DState(conv, in_value * in_value)
-    ops, state = _reduce_schedule(state, value_limit, limb_target, name)
+    ops, state = _reduce_schedule(
+        state, value_limit, limb_target, name, layout_for(rows)
+    )
     _final_certs(state, value_limit, limb_target, name)
     out = _run_fused(
         a.reshape(rows, 1, fq.NLIMBS), b.reshape(rows, 1, fq.NLIMBS), ops, None, ()
@@ -663,7 +1041,8 @@ def execute_plan(
     else:  # pragma: no cover - static schedule
         raise AssertionError(f"{kname}: pallas out-lincomb does not fit f32")
     w = len(lane_state.digits)
-    _lane_cert(w, kname)
+    layout = layout_for(rows)
+    _width_cert(w, kname, layout)
 
     # out-row bound profiles + digit-space borrow constants
     def profile(idx):
@@ -717,7 +1096,7 @@ def execute_plan(
         ), "out_bound.top unsound for its value/limb bounds"
         value_limit, limb_target = out_bound.value_p * P, out_bound.limb
     post_ops, out_state = _reduce_schedule(
-        out_state, value_limit, limb_target, kname
+        out_state, value_limit, limb_target, kname, layout
     )
     _final_certs(out_state, value_limit, limb_target, kname)
 

@@ -51,6 +51,11 @@ the time the process spent in the compile layer.
 ``bls_stage_store_total{stage, outcome}`` counts how each stored stage was
 resolved in this process: ``hit`` (loaded, never traced), ``miss`` (traced
 and stored) or ``invalid`` (a bad entry, deleted, traced and stored again).
+
+``bls_pallas_kernels_total{layout}`` counts the fused Pallas kernel calls
+traced in this process by the block layout their shape chose: ``lane`` or
+``digit_major`` (``ops/bls/pallas_kernels.py``). It counts at trace time, so
+a stage loaded from the store adds nothing.
 """
 
 from __future__ import annotations
@@ -84,6 +89,13 @@ STAGE_STORE_TOTAL = REGISTRY.counter(
     "Device stages resolved from the stage store, by outcome "
     "(hit / miss / invalid; utils/stage_store.py)",
     label_names=("stage", "outcome"),
+)
+
+PALLAS_KERNELS_TOTAL = REGISTRY.counter(
+    "bls_pallas_kernels_total",
+    "Fused Pallas kernel calls traced, by block layout (lane / digit_major; "
+    "ops/bls/pallas_kernels.py)",
+    label_names=("layout",),
 )
 
 _BATCH: contextvars.ContextVar[int] = contextvars.ContextVar("lighthouse_tpu_batch")

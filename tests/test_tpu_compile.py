@@ -92,6 +92,22 @@ def test_fused_kernel_compiles(name, one_chip, tpu_pallas):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+@pytest.mark.parametrize("rows", [64, 16384])
+def test_layout_by_rows_compiles(rows, one_chip, tpu_pallas):
+    """The G1 complete addition (two plans, negative out-map terms) compiles
+    in the layout its rows choose: the lane kernel at 64 rows, the
+    digit-major one, named ``fused_digit_major``, at the 512-key tree sum's
+    first level."""
+    from lighthouse_tpu.ops.bls import curve, pallas_kernels as pk
+
+    p = _u64(one_chip, rows, 3, 25)
+    text = _compile(lambda a, b: curve.point_add(1, a, b), p, p).as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    named = [ln for ln in calls if "fused_digit_major" in ln.split("=")[0]]
+    assert len(calls) == 2
+    assert len(named) == (2 if pk.layout_for(rows) == "digit_major" else 0)
+
+
 def test_u64_lincomb_compiles(one_chip, tpu_pallas):
     """A u64-input lincomb (the fq12 plan's input map) compiles for TPU:
     XLA's X64 rewriter has no rule for a u64 dot_general, so the contraction

@@ -234,3 +234,25 @@ def test_context_follows_the_watchdog_hop():
         assert tracing._BATCH.get(0) == 0
     finally:
         _VAR.reset(token)
+
+
+def test_pallas_kernel_layouts_counted_at_trace_time(monkeypatch):
+    """Each fused Pallas kernel traced counts once under the layout its rows
+    chose, and the counter is on the registry's exposition."""
+    from lighthouse_tpu.ops.bls import fq, pallas_kernels as pk
+    from lighthouse_tpu.utils.metrics import REGISTRY
+
+    monkeypatch.setattr(fq, "_CONV_IMPL", "pallas")
+
+    def counts():
+        return {k[0]: v for k, _, v in tracing.PALLAS_KERNELS_TOTAL.collect()}
+
+    before = counts()
+    small = jax.ShapeDtypeStruct((pk.DIGIT_MAJOR_MIN_ROWS - 1, 25), jnp.uint64)
+    wide = jax.ShapeDtypeStruct((pk.DIGIT_MAJOR_MIN_ROWS, 25), jnp.uint64)
+    jax.eval_shape(lambda a, b: fq.mont_mul(a, b), small, small)
+    jax.eval_shape(lambda a, b: (fq.mont_mul(a, b), fq.mont_mul(b, a)), wide, wide)
+    after = counts()
+    assert after.get("lane", 0) - before.get("lane", 0) == 1
+    assert after.get("digit_major", 0) - before.get("digit_major", 0) == 2
+    assert 'bls_pallas_kernels_total{layout="digit_major"}' in REGISTRY.render()
