@@ -245,16 +245,6 @@ def _verify_sets_tpu(sets) -> bool:
         sig = jnp.concatenate(
             [sig, jnp.broadcast_to(sig[:1], (n_pad - n,) + sig.shape[1:])]
         )
-    # serving-mesh seam (LIGHTHOUSE_MESH_DEVICES): data-parallel over the
-    # device mesh with a cross-device combine; off (the default) keeps the
-    # single-device kernel bit-identical to the pre-mesh engine
-    from . import mesh as bls_mesh
-
-    n_mesh = bls_mesh.serving_mesh_size()
-    if n_mesh > 1:
-        return tb.verify_signature_sets_sharded_h2c(
-            pk_agg, sig, u0, u1, n, bls_mesh.get_mesh(tuple(range(n_mesh)))
-        )
     return tb.verify_signature_sets_device_h2c(pk_agg, sig, u0, u1, n)
 
 

@@ -8,7 +8,11 @@ touches devices lives here:
   unset/``0``/``1``/``off`` disables the mesh (the single-device engine,
   bit-identical to the pre-mesh code path), ``auto`` takes every visible
   device, an integer takes that many. The size is floored to a power of two
-  (fixed-shape compile families; mesh halving stays shape-stable).
+  (fixed-shape compile families; mesh halving stays shape-stable). It
+  drives the chain's sharded tier only (its indexed batches and the
+  firehose, through ``MeshVerifier``) and the slasher's validator
+  sharding; the generic ``bls.verify_signature_sets`` seam always runs on
+  one device.
 * **mesh cache** — one ``jax.sharding.Mesh`` per device subset, so the
   degradation ladder's shrunken meshes (N -> N/2 -> ...) reuse compiled
   programs across calls.

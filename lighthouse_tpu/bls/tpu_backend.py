@@ -479,143 +479,6 @@ def _sharded_prep_stage(mesh, n_pad: int, k_pad: int):
     ))
 
 
-@functools.lru_cache(maxsize=None)
-def _sharded_array_prologue_stage(mesh, n_pad: int):
-    """Sharded twin of ``_prologue_stage`` (pre-aggregated pk/sig arrays)."""
-    from jax.sharding import PartitionSpec as P
-
-    def bls_prologue_sharded(pk_agg, sig, scalars, valid):
-        set_ok, pk_scaled, sig_part = _set_prologue(pk_agg, sig, scalars, valid)
-        pkx, pky = g1.to_affine(pk_scaled)
-        return pkx, pky, sig_part[None], jnp.all(set_ok)[None]
-
-    return jax.jit(jax.shard_map(
-        bls_prologue_sharded, mesh=mesh, in_specs=(P("sets"),) * 4,
-        out_specs=(P("sets"),) * 4,
-        check_vma=False,
-    ))
-
-
-@functools.lru_cache(maxsize=None)
-def _sharded_miller_stage(mesh, n_pad: int):
-    """Per-device Miller loops over the local sets plus the local Fq12
-    product — one [n_dev, 12, 25] partial per device."""
-    from jax.sharding import PartitionSpec as P
-
-    def bls_miller_sharded(pkx, pky, mxa, mya, valid):
-        # backend-dispatched product Miller stage (PR 6): on the digit
-        # backend one shared fq12 accumulator covers the device's whole
-        # shard; invalid pairs contribute the identity either way
-        f = pairing.miller_product(
-            pkx[:, 0, :], pky[:, 0, :], mxa, mya, valid
-        )
-        return f[None], jnp.any(valid)[None]
-
-    return jax.jit(jax.shard_map(
-        bls_miller_sharded, mesh=mesh, in_specs=(P("sets"),) * 5,
-        out_specs=(P("sets"),) * 2,
-        check_vma=False,
-    ))
-
-
-@functools.lru_cache(maxsize=None)
-def _sharded_combine_stage(mesh):
-    """The cross-device epilogue: G2-MSM reduction of the per-device
-    signature partials + Fq12 product of the per-device pairing partials
-    (XLA inserts the collectives over the mesh from the sharded operands),
-    one final Miller loop against -g1, ONE replicated final exponentiation,
-    and the combined verdict."""
-
-    @jax.jit
-    def bls_combine_sharded(partial_f, partial_sig, ok_parts, any_parts):
-        sig_acc = g2.psum(partial_sig)
-        f_all = pairing.fq12_prod(partial_f)
-        sx, sy = g2.to_affine(sig_acc)
-        f_last = pairing.miller_loop(_MG1_X, _MG1_Y, sx, sy)
-        f = tower.fq12_mul(f_all, f_last)
-        ok = tower.fq12_is_one(pairing.final_exponentiation(f))
-        return ok & jnp.all(ok_parts) & jnp.any(any_parts)
-
-    return bls_combine_sharded
-
-
-def _sharded_gathered_kernel(mesh, n_pad: int, k_pad: int):
-    """Multi-chip twin of ``_gathered_kernel``: the chain hot path (cache
-    gather + aggregate + device h2c + signature decompression + RLC
-    verification) data-parallel over the mesh's ``sets`` axis, as FOUR
-    separately jitted shard_map stages (h2c / prep / miller / combine —
-    fused single programs compiled superlinearly, the r3 pathology; staged
-    programs compile independently and cache persistently). Cross-device
-    combines ride the mesh via XLA collectives in the combine stage.
-    Reference semantics: ``crypto/bls/src/impls/blst.rs:37-119``.
-    """
-    h2c_k = _sharded_h2c_stage(mesh, n_pad)
-    prep_k = _sharded_prep_stage(mesh, n_pad, k_pad)
-    miller_k = _sharded_miller_stage(mesh, n_pad)
-    combine_k = _sharded_combine_stage(mesh)
-
-    def verify(cache, idx, mask, u0, u1, sxc0, sxc1, s_flag, sig_wf,
-               scalars, valid):
-        mxa, mya = h2c_k(u0, u1)
-        pkx, pky, partial_sig, ok_parts = prep_k(
-            cache, idx, mask, sxc0, sxc1, s_flag, sig_wf, scalars, valid
-        )
-        partial_f, any_parts = miller_k(pkx, pky, mxa, mya, valid)
-        return combine_k(partial_f, partial_sig, ok_parts, any_parts)
-
-    return verify
-
-
-def verify_indexed_sets_sharded(cache_arr, items, mesh) -> bool:
-    """``verify_indexed_sets_device`` over a mesh with a ``sets`` axis:
-    mainnet-shape batches (ragged per-set key counts, 0-padded to a shared
-    k bucket) data-parallel across chips, pubkey cache replicated."""
-    from .serde import parse_g2_bytes
-    from ..ops.bls import h2c
-    from ..ops.bls_oracle.ciphersuite import DST
-
-    n = len(items)
-    if n == 0:
-        return False
-    n_dev = mesh.devices.size
-    n_pad = ((bucket(max(n, n_dev)) + n_dev - 1) // n_dev) * n_dev
-    k_pad = bucket(max((len(ix) for ix, _, _ in items), default=1))
-
-    idx = np.zeros((n_pad, k_pad), dtype=np.int32)
-    mask = np.zeros((n_pad, k_pad), dtype=bool)
-    sig_bytes = np.zeros((n_pad, 96), dtype=np.uint8)
-    msgs = []
-    for i, (indices, msg, sb) in enumerate(items):
-        k = len(indices)
-        if k > 0:
-            idx[i, :k] = np.asarray(indices, dtype=np.int32)
-            mask[i, :k] = True
-        msgs.append(msg)
-        sig_bytes[i] = np.frombuffer(sb, dtype=np.uint8)
-
-    parsed = parse_g2_bytes(sig_bytes)
-    sig_wf = parsed["wf_ok"] & ~parsed["is_inf"]
-    u0, u1 = h2c.hash_to_field_batch(msgs, DST, n_pad)
-    scalars = np.array(
-        [secrets.randbits(RAND_BITS) or 1 for _ in range(n_pad)], dtype=np.uint64
-    )
-    valid = np.arange(n_pad) < n
-    ok = _sharded_gathered_kernel(mesh, n_pad, k_pad)(
-        cache_arr,
-        jnp.asarray(idx),
-        jnp.asarray(mask),
-        u0,
-        u1,
-        jnp.asarray(parsed["x_c0"]),
-        jnp.asarray(parsed["x_c1"]),
-        jnp.asarray(parsed["s_flag"]),
-        jnp.asarray(sig_wf),
-        jnp.asarray(scalars),
-        jnp.asarray(valid),
-    )
-    return bool(np.asarray(ok))
-
-
 def _local_pair_verdict(pkx, pky, mxa, mya, sig_part, ok_part, valid):
     """Shard-local pairing epilogue for the PER-SHARD-verdict serving path:
     the device's local Miller product, one local Miller loop of the shard's
@@ -782,90 +645,6 @@ def verify_indexed_shards_pershard(cache_arr, shard_items, mesh) -> np.ndarray:
     staged = stage_indexed_shards(shard_items, cap)
     staged = put_staged(staged, mesh)
     return verify_staged_pershard(cache_arr, staged, mesh)
-
-
-def _sharded_verify_kernel(mesh, n_pad: int):
-    """Multi-chip twin of ``_verify_kernel``: dp over signature sets on the
-    mesh's ``sets`` axis, as three staged shard_map jits (array prologue /
-    miller / combine) sharing the gathered path's stages. Reference
-    semantics: ``crypto/bls/src/impls/blst.rs:37-119``.
-    """
-    pro_k = _sharded_array_prologue_stage(mesh, n_pad)
-    miller_k = _sharded_miller_stage(mesh, n_pad)
-    combine_k = _sharded_combine_stage(mesh)
-
-    def verify(pk_agg, sig, mx, my, scalars, valid):
-        pkx, pky, partial_sig, ok_parts = pro_k(pk_agg, sig, scalars, valid)
-        partial_f, any_parts = miller_k(pkx, pky, mx, my, valid)
-        return combine_k(partial_f, partial_sig, ok_parts, any_parts)
-
-    return verify
-
-
-def verify_signature_sets_sharded(
-    pk_agg, sig, msg_x, msg_y, n_real: int, mesh
-) -> bool:
-    """Sharded batch verification over a ``Mesh`` with a ``sets`` axis.
-
-    Pads the batch up to a multiple of the mesh size (padded entries masked
-    invalid), draws fresh 64-bit scalars host-side, and runs the dp +
-    ICI-combine kernel.
-    """
-    if n_real == 0:
-        return False
-    n_dev = mesh.devices.size
-    n = pk_agg.shape[0]
-    # power-of-two bucket (shape-stable compiles), rounded to a mesh multiple
-    n_pad = ((bucket(max(n, n_dev)) + n_dev - 1) // n_dev) * n_dev
-    if n_pad != n:
-        pad = n_pad - n
-
-        def _pad(a):
-            return jnp.concatenate(
-                [a, jnp.zeros((pad,) + a.shape[1:], dtype=a.dtype)], axis=0
-            )
-
-        pk_agg, sig, msg_x, msg_y = map(_pad, (pk_agg, sig, msg_x, msg_y))
-    scalars = np.array(
-        [secrets.randbits(RAND_BITS) or 1 for _ in range(n_pad)], dtype=np.uint64
-    )
-    valid = np.arange(n_pad) < n_real
-    ok = _sharded_verify_kernel(mesh, n_pad)(
-        pk_agg, sig, msg_x, msg_y, jnp.asarray(scalars), jnp.asarray(valid)
-    )
-    return bool(np.asarray(ok))
-
-
-def verify_signature_sets_sharded_h2c(pk_agg, sig, u0, u1, n_real: int,
-                                      mesh) -> bool:
-    """Sharded twin of ``verify_signature_sets_device_h2c`` — the generic
-    ``bls.verify_signature_sets`` seam's mesh path: device h2c + prologue +
-    Miller partials data-parallel over the ``sets`` axis, cross-device
-    G2-MSM / Fq12-product combine, ONE final exponentiation. Inputs may be
-    padded to any length ≥ n_real; they are re-padded to a mesh-multiple
-    bucket here (broadcast, masked invalid)."""
-    if n_real == 0:
-        return False
-    n_dev = mesh.devices.size
-    n = pk_agg.shape[0]
-    n_pad = ((bucket(max(n, n_dev)) + n_dev - 1) // n_dev) * n_dev
-    if n_pad != n:
-        def _pad(a):
-            return jnp.concatenate(
-                [a, jnp.broadcast_to(a[:1], (n_pad - n,) + a.shape[1:])]
-            )
-
-        pk_agg, sig, u0, u1 = map(_pad, (pk_agg, sig, u0, u1))
-    scalars = np.array(
-        [secrets.randbits(RAND_BITS) or 1 for _ in range(n_pad)],
-        dtype=np.uint64,
-    )
-    valid = np.arange(n_pad) < n_real
-    mx, my = _sharded_h2c_stage(mesh, n_pad)(u0, u1)
-    ok = _sharded_verify_kernel(mesh, n_pad)(
-        pk_agg, sig, mx, my, jnp.asarray(scalars), jnp.asarray(valid)
-    )
-    return bool(np.asarray(ok))
 
 
 def verify_signature_sets_device(pk_agg, sig, msg_x, msg_y, n_real: int) -> bool:

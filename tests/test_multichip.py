@@ -1,19 +1,20 @@
-"""Multi-chip sharded verification on the virtual 8-device CPU mesh.
+"""Multi-chip BLS verification on the virtual 8-device CPU mesh.
 
-Locks down the driver's ``dryrun_multichip`` path: the full dp-over-sets
-shard_map kernel with cross-device G2-MSM + Fq12-product combines
-(``lighthouse_tpu/bls/tpu_backend.py::verify_signature_sets_sharded``), the
-semantics of ``crypto/bls/src/impls/blst.rs:37-119``: one valid batch passes,
-one poisoned set fails the whole batch.
+Locks down the one multi-chip design, the per-shard-verdict path
+(``lighthouse_tpu/bls/tpu_backend.py::verify_indexed_shards_pershard`` and
+its staged form ``verify_staged_pershard``): signature sets split into one
+sub-batch per device, the pubkey cache replicated, and each device's
+sub-batch checked with its own final exponentiation, so a bad set condemns
+only its own shard. Each shard's verdict must equal single-device
+verification of that sub-batch (the semantics of
+``crypto/bls/src/impls/blst.rs:37-119``).
+``__graft_entry__.dryrun_multichip`` runs the same path.
 """
 
 import numpy as np
 import pytest
 
 import jax
-
-from lighthouse_tpu.bls.tpu_backend import verify_signature_sets_sharded
-from lighthouse_tpu.ops.bls import g2
 
 
 # JAX compile-heavy tier (see pytest.ini).
@@ -30,10 +31,10 @@ def mesh():
 
 
 @pytest.fixture(scope="module")
-def example_sets():
-    from __graft_entry__ import _example_sets
+def indexed_fixture():
+    from __graft_entry__ import _indexed_fixture
 
-    return _example_sets(8)
+    return _indexed_fixture(16, n_validators=24)
 
 
 def test_dryrun_multichip_entrypoint():
@@ -43,73 +44,8 @@ def test_dryrun_multichip_entrypoint():
     __graft_entry__.dryrun_multichip(8)
 
 
-def test_sharded_verify_accepts_valid_batch(mesh, example_sets):
-    pk, sig, mx, my, _ = example_sets
-    assert verify_signature_sets_sharded(pk, sig, mx, my, 8, mesh)
-
-
-def test_sharded_verify_rejects_poisoned_set(mesh, example_sets):
-    pk, sig, mx, my, _ = example_sets
-    bad_sig = sig.at[3].set(g2.neg(sig[3]))  # negate one signature
-    assert not verify_signature_sets_sharded(pk, bad_sig, mx, my, 8, mesh)
-
-
-def test_sharded_verify_pads_ragged_batch(mesh, example_sets):
-    """Batch smaller than the mesh is padded and masked, not rejected."""
-    pk, sig, mx, my, _ = example_sets
-    assert verify_signature_sets_sharded(pk[:5], sig[:5], mx[:5], my[:5], 5, mesh)
-
-
-def test_sharded_verify_empty_batch_is_false(mesh, example_sets):
-    pk, sig, mx, my, _ = example_sets
-    assert not verify_signature_sets_sharded(pk, sig, mx, my, 0, mesh)
-
-
 # ---------------------------------------------------------------------------
-# Fused gather path (the gossip hot path) sharded over the mesh
-# ---------------------------------------------------------------------------
-
-
-@pytest.fixture(scope="module")
-def indexed_fixture():
-    from __graft_entry__ import _indexed_fixture
-
-    return _indexed_fixture(16, n_validators=24)
-
-
-def test_sharded_gather_accepts_ragged_batch(mesh, indexed_fixture):
-    """16 sets with ragged key counts (1..3) over 8 devices, cache
-    replicated — the mainnet gossip-batch layout at test scale."""
-    from lighthouse_tpu.bls.tpu_backend import verify_indexed_sets_sharded
-
-    cache, items = indexed_fixture
-    assert verify_indexed_sets_sharded(cache, items, mesh)
-
-
-def test_sharded_gather_rejects_poisoned_set(mesh, indexed_fixture):
-    from lighthouse_tpu.bls.tpu_backend import verify_indexed_sets_sharded
-
-    cache, items = indexed_fixture
-    poisoned = list(items)
-    ix, msg, _ = poisoned[11]
-    _, _, other_sig = poisoned[0]
-    poisoned[11] = (ix, msg, other_sig)
-    assert not verify_indexed_sets_sharded(cache, poisoned, mesh)
-
-
-def test_sharded_gather_agrees_with_single_chip(mesh, indexed_fixture):
-    from lighthouse_tpu.bls.tpu_backend import (
-        verify_indexed_sets_device,
-        verify_indexed_sets_sharded,
-    )
-
-    cache, items = indexed_fixture
-    assert verify_indexed_sets_sharded(cache, items, mesh) == \
-        verify_indexed_sets_device(cache, items)
-
-
-# ---------------------------------------------------------------------------
-# Per-shard-verdict serving path (the multi-chip firehose tier, ISSUE 10)
+# Per-shard-verdict serving path (the multi-chip firehose tier)
 # ---------------------------------------------------------------------------
 
 
@@ -118,7 +54,100 @@ def _as_shards(items, n_shards):
     return [items[i * per:(i + 1) * per] for i in range(n_shards)]
 
 
+def _split(items, sizes):
+    """Consecutive shards of the given sizes."""
+    ends = np.cumsum(sizes)
+    return [items[e - n:e] for n, e in zip(sizes, ends)]
+
+
+def _with_sig_of(items, i, j):
+    """``items`` with set i carrying set j's signature (a wrong signature)."""
+    out = list(items)
+    ix, msg, _ = out[i]
+    out[i] = (ix, msg, items[j][2])
+    return out
+
+
+def _verdicts(cache, shards, mesh):
+    from lighthouse_tpu.bls.tpu_backend import verify_indexed_shards_pershard
+
+    oks = verify_indexed_shards_pershard(cache, shards, mesh)
+    assert oks.shape == (len(shards),), oks.shape
+    return [bool(o) for o in oks]
+
+
+# Each case runs at the shapes the rest of this file compiles (8 shards,
+# cap 4, k_pad 4, the 24-key cache), so none adds a compile.
+
+
+def _ragged_batch_is_padded(cache, items, mesh):
+    shards = [[it] for it in items[:5]] + [[] for _ in range(3)]
+    assert _verdicts(cache, shards, mesh) == [True] * 5 + [False] * 3
+
+
+def _empty_batch_is_false(cache, items, mesh):
+    assert _verdicts(cache, [[] for _ in range(8)], mesh) == [False] * 8
+
+
+def _poisoned_set_condemns_its_shard(cache, items, mesh):
+    # set 6 sits in shard 2 of the ragged layout; shard 3 is empty
+    shards = _split(_with_sig_of(items, 6, 0), (3, 1, 4, 0, 2, 1, 3, 2))
+    expect = [True, True, False, False, True, True, True, True]
+    assert _verdicts(cache, shards, mesh) == expect
+
+
+def _uneven_shards_agree_with_single_chip(cache, items, mesh):
+    from lighthouse_tpu.bls.tpu_backend import verify_indexed_sets_device
+
+    shards = _split(_with_sig_of(items, 12, 3), (1, 2, 3, 4, 1, 2, 1, 2))
+    oks = _verdicts(cache, shards, mesh)
+    assert oks == [verify_indexed_sets_device(cache, sh) for sh in shards]
+    assert oks.count(False) == 1, oks
+
+
+def _keyless_set_condemns_its_shard(cache, items, mesh):
+    keyless = list(items)
+    _, msg, sig = keyless[6]
+    keyless[6] = ([], msg, sig)
+    expect = [True] * 8
+    expect[3] = False  # 2 sets a shard
+    assert _verdicts(cache, _as_shards(keyless, 8), mesh) == expect
+
+
+def _shard_count_must_match_mesh(cache, items, mesh):
+    from lighthouse_tpu.bls.tpu_backend import verify_indexed_shards_pershard
+
+    with pytest.raises(ValueError, match="7 shards for a 8-device mesh"):
+        verify_indexed_shards_pershard(cache, _as_shards(items[:14], 7), mesh)
+
+
+def _shard_over_cap_is_refused(cache, items, mesh):
+    from lighthouse_tpu.bls.tpu_backend import stage_indexed_shards
+
+    shards = [items[:5]] + [[] for _ in range(7)]
+    with pytest.raises(ValueError, match="shard 0 holds 5 items > cap 4"):
+        stage_indexed_shards(shards, 4)
+
+
+VERDICT_CASES = (
+    _ragged_batch_is_padded,
+    _empty_batch_is_false,
+    _poisoned_set_condemns_its_shard,
+    _uneven_shards_agree_with_single_chip,
+    _keyless_set_condemns_its_shard,
+    _shard_count_must_match_mesh,
+    _shard_over_cap_is_refused,
+)
+
+
 class TestPerShardVerdicts:
+    @pytest.mark.parametrize(
+        "case", VERDICT_CASES, ids=lambda f: f.__name__.lstrip("_")
+    )
+    def test_verdict_case(self, mesh, indexed_fixture, case):
+        cache, items = indexed_fixture
+        case(cache, items, mesh)
+
     def test_valid_batch_all_shards_verify(self, mesh, indexed_fixture):
         from lighthouse_tpu.bls.tpu_backend import (
             verify_indexed_shards_pershard,
@@ -212,8 +241,9 @@ class TestPerShardVerdicts:
 
 
 class TestGenericSeamMeshPath:
-    """LIGHTHOUSE_MESH_DEVICES routes the generic ``bls.verify_signature_sets``
-    seam over the mesh; verdicts agree with the single-device path."""
+    """LIGHTHOUSE_MESH_DEVICES drives the chain's sharded tier only: the
+    generic ``bls.verify_signature_sets`` seam gives the same verdicts with
+    it set or unset."""
 
     @pytest.fixture()
     def sets(self):
@@ -252,46 +282,23 @@ class TestGenericSeamMeshPath:
 
 @pytest.mark.slow  # two extra cold compiles (~7 min); nightly tier
 def test_sharded_gather_per_device_work_drops_with_mesh_size():
-    """The SPMD module's per-device FLOPs must shrink as the mesh grows at
+    """The SPMD modules' per-device FLOPs must shrink as the mesh grows at
     fixed batch size: the sets axis is genuinely data-parallel, not
     replicated (SURVEY §2.4 ICI note)."""
     from jax.sharding import Mesh
 
     from lighthouse_tpu.bls import tpu_backend as tb
 
-    import jax.numpy as jnp
-
     devs = jax.devices()
     n_pad, k_pad, n_val = 32, 4, 16
-    u = jax.ShapeDtypeStruct((n_pad, 2, 25), jnp.uint64)
     flops = {}
     for n_dev in (2, 8):
         mesh = Mesh(np.array(devs[:n_dev]), axis_names=("sets",))
         total = 0.0
-        # sum per-device cost over the sharded h2c + prep + miller stages
-        # (every data-parallel stage of the staged kernel; the combine stage
-        # is the replicated epilogue and is excluded on both sides)
-        for lowered in (
-            tb._sharded_h2c_stage(mesh, n_pad).lower(u, u),
-            tb._sharded_prep_stage(mesh, n_pad, k_pad).lower(
-                jax.ShapeDtypeStruct((n_val, 3, 25), jnp.uint64),
-                jax.ShapeDtypeStruct((n_pad, k_pad), jnp.int32),
-                jax.ShapeDtypeStruct((n_pad, k_pad), jnp.bool_),
-                jax.ShapeDtypeStruct((n_pad, 25), jnp.uint64),
-                jax.ShapeDtypeStruct((n_pad, 25), jnp.uint64),
-                jax.ShapeDtypeStruct((n_pad,), jnp.uint64),
-                jax.ShapeDtypeStruct((n_pad,), jnp.bool_),
-                jax.ShapeDtypeStruct((n_pad,), jnp.uint64),
-                jax.ShapeDtypeStruct((n_pad,), jnp.bool_),
-            ),
-            tb._sharded_miller_stage(mesh, n_pad).lower(
-                jax.ShapeDtypeStruct((n_pad, 1, 25), jnp.uint64),
-                jax.ShapeDtypeStruct((n_pad, 1, 25), jnp.uint64),
-                u,
-                u,
-                jax.ShapeDtypeStruct((n_pad,), jnp.bool_),
-            ),
-        ):
+        # sum per-device cost over the h2c, prep and verdict stages, every
+        # stage of the per-shard path (each device's one final
+        # exponentiation is the only work that does not shrink)
+        for _, lowered in tb.sharded_stage_lowerings(mesh, n_pad, k_pad, n_val):
             cost = lowered.compile().cost_analysis()
             if isinstance(cost, list):
                 cost = cost[0]

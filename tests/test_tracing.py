@@ -157,8 +157,6 @@ def test_stage_jits_have_stable_names():
     assert tb._sharded_h2c_stage(mesh, 4).__name__ == "bls_h2c_sharded"
     assert tb._sharded_prep_stage(mesh, 4, 4).__name__ == "bls_prep_sharded"
     assert tb._sharded_verdict_stage(mesh, 4).__name__ == "bls_verdict_sharded"
-    assert tb._sharded_miller_stage(mesh, 4).__name__ == "bls_miller_sharded"
-    assert tb._sharded_combine_stage(mesh).__name__ == "bls_combine_sharded"
 
 
 def test_compile_listener_counts_one_compile_per_new_program():
