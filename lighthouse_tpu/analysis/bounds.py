@@ -299,6 +299,14 @@ def graph_registry(batch: int) -> list[tuple]:
          lambda a: pk.execute_plan(
              plans.FROB12, a, a, plans.PUB_BOUND, plans.PUB_BOUND, "frob12"
          ), (e12,)),
+        # an fq12 product at 128 x batch rows: at batch 1 the packed
+        # layout (8 lanes a block, out-rows mixed across sublanes), at 32
+        # the full-block digit-major one
+        ("pallas.execute_fq12_mul_packed",
+         lambda a, b: pk.execute_plan(
+             plans.MUL12, a, b, plans.PUB_BOUND, plans.PUB_BOUND,
+             "fq12_mul_packed",
+         ), (jax.ShapeDtypeStruct((128,) + B + (12, 25), u64),) * 2),
         # ops/kzg/frops.py — the Fr (scalar-field) limb stack of the
         # PeerDAS cell-proof engine (ISSUE 16), the SECOND field on the
         # shared fq conv seam: RLC weight products, the interpolation dot,

@@ -14,7 +14,7 @@ memory per call. Here the WHOLE pipeline after the input lincombs runs as ONE
   intermediate is proven < 2^24, the f32 integer-exactness cap, so the whole
   kernel is EXACT integer arithmetic in float registers.
 
-* **Two block layouts, chosen by the call's rows** (``layout_for``).
+* **Three block layouts, chosen by the call's rows** (``layout_for``).
 
   - *Lane* (below ``DIGIT_MAJOR_MIN_ROWS``): every in-kernel array is a
     2-D ``[8, 128]`` f32 plane — one vreg: digit position d of a row sits
@@ -26,11 +26,17 @@ memory per call. Here the WHOLE pipeline after the input lincombs runs as ONE
     i)``: a lane broadcast, a lane rotation and an FMA per step; the 101
     conv positions stay below lane 128, so no rotation wraps a non-zero
     digit.
-  - *Digit-major* (from ``DIGIT_MAJOR_MIN_ROWS`` up): operands are ``[lanes,
+  - *Digit-major* (from ``FULL_BLOCK_ROWS`` up): operands are ``[lanes,
     51, rows / 128, 128]``, so one vreg holds one digit of 1,024 rows and
     every schedule step is elementwise across digit planes: the conv is
     multiply-adds of whole planes, the fold multiplies by rows of an F8
     table, a carry moves one plane into the next. No op crosses lanes.
+  - *Packed* (``DIGIT_MAJOR_MIN_ROWS`` up to ``FULL_BLOCK_ROWS``): the
+    digit-major body on a block whose 8 sublanes hold G lanes of the plan,
+    each on the 128-row sublanes its rows need, so every vector op still
+    fills the vreg. The out-map mixes the G lanes into the sublanes of its
+    out-rows with sublane rotations; the out-rows are reduced G at a time.
+    With G = 1 it is the digit-major kernel on one block.
 
 * **Congruence fold.** Digit positions >= 48 (weight 2^384) fold through
   constant rows F8[h] = digits(2^(8*(48+h)) mod p).
@@ -41,7 +47,7 @@ memory per call. Here the WHOLE pipeline after the input lincombs runs as ONE
   constants == 0 mod p), so an fq12 multiply still reduces 12 rows, not 54
   lanes — the plans.py contract, fused.
 
-Both layouts replay the same static schedule on the same exact integers, so
+Every layout replays the same static schedule on the same exact integers, so
 their outputs agree bit for bit.
 
 Every bound the schedule relies on is recorded as a trace-time ``fq._cert``
@@ -152,10 +158,12 @@ def _record_vmem(tile: int, L: int, rows_p: int, out_key) -> None:
     })
 
 
-def _record_dm_vmem(sub, L, rows_p, pre_ops, out_key, post_ops) -> None:
-    """The digit-major twin of _record_vmem: one grid step holds one lane's
-    operand blocks, the out-rows' block, the F8 table and the conv /
-    accumulator scratch."""
+def _record_dm_vmem(
+    layout, sub, G, L, rows_p, pre_ops, out_key, post_ops
+) -> None:
+    """The digit-major twin of _record_vmem: one grid step holds a lane
+    group's operand blocks, the out-groups' block, the F8 table and the
+    conv / accumulator scratch."""
     if _VMEM_SINK is None:
         return
     wt, wa = _dm_scratch_widths(pre_ops, out_key, post_ops)
@@ -163,12 +171,13 @@ def _record_dm_vmem(sub, L, rows_p, pre_ops, out_key, post_ops) -> None:
         R, _, _, _, n_pass = _OUT_TABLE[out_key]
     else:
         R, n_pass = L, 0
-    est = _dm_vmem(sub, R, n_pass, wt, wa)
+    est = _dm_vmem(G * sub, -(-R // G), -(-n_pass // G), wt, wa)
     _VMEM_SINK.append({
-        "layout": "digit_major",
+        "layout": layout,
         "tile": sub * _W,
         "lanes": L,
-        "grid": rows_p // (sub * _W) * L,
+        "group": G,
+        "grid": rows_p // (sub * _W) * -(-L // G),
         "n_rows_out": R,
         "n_pass": n_pass,
         **est,
@@ -562,11 +571,19 @@ def _build_call(
 # every step of the schedule is elementwise across planes. The conv is plain
 # multiply-adds of whole planes, the fold multiplies the 48 low planes by
 # rows of an F8 table held in VMEM, a split carries one plane into the next;
-# nothing crosses lanes. The grid walks (row block, lane); a plan's out-rows
-# accumulate over the lane axis in VMEM and are reduced at the last lane.
-# Results are the lane kernel's, bit for bit: the schedule and its bounds
-# are the same, and every intermediate is an exact integer below 2^24, so
-# the order of the sums does not matter.
+# nothing crosses lanes. The grid walks (row block, lane group); a plan's
+# out-rows accumulate over the lane groups in VMEM and are reduced at the
+# last one. Results are the lane kernel's, bit for bit: the schedule and its
+# bounds are the same, and every intermediate is an exact integer below
+# 2^24, so the order of the sums does not matter.
+#
+# A call under 1,024 rows needs S < 8 sublanes a lane, so its block packs G
+# = 8 / S lanes: lane g of a group on sublanes g S .. g S + S - 1, out-row
+# g of an out-group likewise. Conv, splits and
+# folds do not care which lane a sublane holds. The out-map adds, for each
+# rotation k of the group's sublanes by k S, the rotated planes times a
+# plane of coefficients: sublane slot g of out-group o takes coefficient
+# [o G + g, lane (g - k) mod G] (zero: the rotation is skipped).
 #
 # Measured on a TPU v5e (PERF.md): the F8 entries as constants in the code
 # ran the kernel 6-71% faster than the table, but made it 5x larger to
@@ -584,12 +601,21 @@ _FOLD8_DM = np.ascontiguousarray(np.broadcast_to(
 
 
 def _dm_rows(rows: int) -> tuple[int, int]:
-    """(sublanes S of a block, padded row count) for the digit-major layout:
-    S = 8 (1,024 rows a grid step) from 1,024 rows up; below that one block
-    of the next power of two of 128-row sublanes."""
+    """(sublanes S a lane takes in a block, padded row count) for the
+    digit-major layouts: S = 8 (1,024 rows a grid step) from 1,024 rows up;
+    below that one block of the next power of two of 128-row sublanes."""
     ns = -(-rows // _W)
     s = _DM_S if ns >= _DM_S else 1 << (ns - 1).bit_length()
     return s, -(-ns // s) * s * _W
+
+
+def _dm_group(layout: str, sub: int, lanes: int, rows_out: int) -> int:
+    """Lanes G a block packs: in the packed layout as many as its 8
+    sublanes hold (zero lanes pad a small plan, so every sublane rotation
+    is one of a whole vreg), unless one lane feeds one out-row; else 1."""
+    if layout != "packed" or max(lanes, rows_out) == 1:
+        return 1
+    return _DM_S // sub
 
 
 def _dm_widths(w: int, ops) -> tuple[int, int]:
@@ -698,10 +724,28 @@ def _dm_write(o, t, w: int) -> None:
         o[n:_OUT_D] = jnp.zeros((_OUT_D - n,) + o.shape[1:], jnp.float32)
 
 
+def _dm_tables(G, sub, L, R, n_pass, coef, oconst):
+    """The out-map's tables padded to whole groups: the coefficients
+    [out-rows, lanes | pass-through inputs] (SMEM), lane group i in columns
+    i G .. i G + G - 1 and pass group j after the last lane group; and the
+    borrow constants [out-groups, G sub, 128] (VMEM), out-row g of a group
+    on the sublanes of slot g with digit d in lane d."""
+    n_in, n_pg, n_out = -(-L // G), -(-n_pass // G), -(-R // G)
+    c = np.zeros((n_out * G, (n_in + n_pg) * G), np.float32)
+    c[:R, :L] = coef[:, :L]
+    c[:R, n_in * G : n_in * G + n_pass] = coef[:, L:]
+    k = np.zeros((n_out * G, _W), np.float32)
+    k[:R, : oconst.shape[1]] = oconst
+    k = np.repeat(k.reshape(n_out, G, _W), sub, axis=1)
+    return c, k
+
+
 @functools.lru_cache(maxsize=512)
 def _build_dm_call(
+    layout: str,
     rows_p: int,
     sub: int,
+    G: int,
     n_lanes: int,
     pre_ops: tuple,
     out_key,
@@ -710,31 +754,37 @@ def _build_dm_call(
 ):
     """Build (and cache) the digit-major pallas_call for one signature.
 
-    Operands are digit planes ``[lanes, D, rows_p / 128, 128]``; the grid is
-    (row block, lane), a block holds ``sub`` x 128 rows of one lane. Without
-    an out-map each lane's reduced planes are its output; with one, the
-    lanes' pre-split conv planes accumulate into the out-rows (borrow
-    constants and pass-through terms seeded at the first lane) and the
-    out-rows are reduced and written at the last lane."""
+    Operands are digit planes ``[lane groups, D, G rows_p / 128, 128]``; the
+    grid is (row block, lane group), a block holds ``sub`` x 128 rows of
+    each of G lanes (G > 1 only in the packed layout, one row block); the
+    kernel is named for its layout. Without an out-map each group's reduced
+    planes are its output; with one, the groups' pre-split conv planes
+    accumulate into the out-groups (borrow constants and pass-through terms
+    seeded at the first group) and the out-groups are reduced and written
+    at the last."""
     L = n_lanes
-    grid = (rows_p // (sub * _W), L)
-    plane = (sub, _W)
+    S = G * sub
+    n_in = -(-L // G)
+    grid = (rows_p // (sub * _W), n_in)
+    plane = (S, _W)
     wt, wa = _dm_scratch_widths(pre_ops, out_key, post_ops)
     has_out = out_key is not None
     if has_out:
         R, mpos_np, mneg_np, oconst_np, n_pass = _OUT_TABLE[out_key]
-        # signed out-map coefficients of the lanes and of the pass-through
-        # inputs, read as SMEM scalars
-        coef_np = (mpos_np - mneg_np).astype(np.float32)
+        coef_np, oconst_np = _dm_tables(
+            G, sub, L, R, n_pass, (mpos_np - mneg_np).astype(np.float32),
+            oconst_np,
+        )
+        n_out, n_pg = -(-R // G), -(-n_pass // G)
     else:
-        R, n_pass = L, 0
+        n_out, n_pg = n_in, 0
 
     def body(*refs):
         a_ref, b_ref, f8_ref = refs[:3]
         rest = list(refs[3:])
         if has_out:
             coef_ref, oconst_ref = rest.pop(0), rest.pop(0)
-        ain_ref = rest.pop(0) if n_pass else None
+        ain_ref = rest.pop(0) if n_pg else None
         o_ref, t_ref = rest[0], rest[1]
 
         _dm_conv(a_ref.at[0], b_ref.at[0], t_ref)
@@ -745,101 +795,123 @@ def _build_dm_call(
             return
 
         acc_ref = rest[2]
-        lane = pl.program_id(1)
+        group = pl.program_id(1)
+        sublane = jax.lax.broadcasted_iota(jnp.int32, plane, 0)
 
-        def rows_loop(fn):
-            def step(r, c):
-                fn(r)
+        def out_loop(fn):
+            def step(o, c):
+                fn(o)
                 return c
 
-            jax.lax.fori_loop(0, R, step, 0)
+            jax.lax.fori_loop(0, n_out, step, 0)
 
-        def accumulate(r, c, src, n):
-            @pl.when(c != 0.0)
-            def _():
-                acc_ref[r, 0:n] = acc_ref[r, 0:n] + src[0:n] * c  # lint: allow(impure-closure) — ref store
+        def mix(o, src, n, col):
+            """acc[o] += the out-map terms of the G lanes of src, whose
+            coefficients sit in columns col .. col + G - 1."""
+            for k in range(G):
+                cs = [coef_ref[o * G + g, col + (g - k) % G] for g in range(G)]
+                used = cs[0] != 0.0
+                for c in cs[1:]:
+                    used = jnp.logical_or(used, c != 0.0)
 
-        @pl.when(lane == 0)
+                @pl.when(used)
+                def _(k=k, cs=cs):
+                    x = src[0:n]
+                    if k:  # lint: allow(tracer-branch) — k is the static rotation
+                        x = pltpu.roll(x, k * sub, 1)
+                    c = cs[0]
+                    if G > 1:  # coefficient of slot g on its sublanes
+                        c = jnp.full(plane, c, jnp.float32)
+                        for g in range(1, G):
+                            c = jnp.where(sublane >= g * sub, cs[g], c)
+                    acc_ref[o, 0:n] = acc_ref[o, 0:n] + x * c  # lint: allow(impure-closure) — ref store
+
+        @pl.when(group == 0)
         def _seed():
             # borrow constants (zero for rows without a negative term), then
             # the pass-through terms of the raw input
-            def seed(r):
-                acc_ref[r, 0:w] = jnp.stack(  # lint: allow(impure-closure) — ref store
-                    [jnp.full(plane, oconst_ref[r, d], jnp.float32) for d in range(w)]
+            def seed(o):
+                oc = oconst_ref[o]
+                acc_ref[o, 0:w] = jnp.stack(  # lint: allow(impure-closure) — ref store
+                    [_bcast_lane(oc, d) for d in range(w)]
                 )
-                for j in range(n_pass):
-                    accumulate(r, coef_ref[r, L + j], ain_ref.at[j], _D)
+                for j in range(n_pg):
+                    mix(o, ain_ref.at[j], _D, (n_in + j) * G)
 
-            rows_loop(seed)
+            out_loop(seed)
 
-        rows_loop(lambda r: accumulate(r, coef_ref[r, lane], t_ref, w))
+        out_loop(lambda o: mix(o, t_ref, w, group * G))
 
-        @pl.when(lane == L - 1)
+        @pl.when(group == n_in - 1)
         def _reduce():
-            def reduce_row(r):
-                row = acc_ref.at[r]
-                _dm_write(o_ref.at[r], row, _dm_replay(row, w, post_ops, f8_ref))
+            def reduce_out(o):
+                row = acc_ref.at[o]
+                _dm_write(o_ref.at[o], row, _dm_replay(row, w, post_ops, f8_ref))
 
-            rows_loop(reduce_row)
+            out_loop(reduce_out)
 
-    def lane_bs(n_dig):
-        return pl.BlockSpec((1, n_dig, sub, _W), lambda i, l: (l, 0, i, 0))
-
-    def smem():
-        return pl.BlockSpec(memory_space=pltpu.SMEM)
+    def group_bs(n_dig):
+        return pl.BlockSpec((1, n_dig, S, _W), lambda i, l: (l, 0, i, 0))
 
     in_specs = [
-        lane_bs(_D), lane_bs(_D),
+        group_bs(_D), group_bs(_D),
         pl.BlockSpec(_FOLD8_DM.shape, lambda i, l: (0, 0, 0, 0)),
     ]
     operands_const = [_FOLD8_DM]
-    scratch = [pltpu.VMEM((wt, sub, _W), jnp.float32)]
+    scratch = [pltpu.VMEM((wt, S, _W), jnp.float32)]
     if has_out:
-        in_specs += [smem(), smem()]
+        in_specs += [
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(oconst_np.shape, lambda i, l: (0, 0, 0)),
+        ]
         operands_const += [coef_np, oconst_np]
-        scratch.append(pltpu.VMEM((R, wa, sub, _W), jnp.float32))
-        out_spec = pl.BlockSpec((R, _OUT_D, sub, _W), lambda i, l: (0, 0, i, 0))
+        scratch.append(pltpu.VMEM((n_out, wa, S, _W), jnp.float32))
+        out_spec = pl.BlockSpec((n_out, _OUT_D, S, _W), lambda i, l: (0, 0, i, 0))
     else:
-        out_spec = lane_bs(_OUT_D)
-    if n_pass:
+        out_spec = group_bs(_OUT_D)
+    if n_pg:
         in_specs.append(
-            pl.BlockSpec((n_pass, _D, sub, _W), lambda i, l: (0, 0, i, 0))
+            pl.BlockSpec((n_pg, _D, S, _W), lambda i, l: (0, 0, i, 0))
         )
 
+    name = "fused_dm_packed" if layout == "packed" else "fused_digit_major"
     call = pl.pallas_call(
         body,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(
-            (R, _OUT_D, rows_p // _W, _W), jnp.float32
+            (n_out, _OUT_D, G * rows_p // _W, _W), jnp.float32
         ),
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=_dm_vmem_limit(_dm_vmem(sub, R, n_pass, wt, wa)),
+            vmem_limit_bytes=_dm_vmem_limit(_dm_vmem(S, n_out, n_pg, wt, wa)),
         ),
         interpret=interpret,
-        name="fused_digit_major",
+        name=name,
     )
 
     def run(A_d, B_d, Ain_d=None):
         args = [A_d, B_d] + [jnp.asarray(c) for c in operands_const]
-        if n_pass:
+        if n_pg:
             args.append(Ain_d)
-        with jax.enable_x64(False), jax.named_scope("fused_digit_major"):
+        with jax.enable_x64(False), jax.named_scope(name):
             return call(*args)
 
     return run
 
 
-def _dm_vmem(sub, R, n_pass, wt, wa) -> dict:
-    """VMEM working set of one digit-major grid step, in bytes."""
-    plane = sub * _W * 4
-    blocks_in = (2 * _D + n_pass * _D) * plane
-    block_out = R * _OUT_D * plane if wa else _OUT_D * plane
-    scratch = (wt + R * wa) * plane
-    const = _FOLD8_DM.nbytes
+def _dm_vmem(S, n_out, n_pg, wt, wa) -> dict:
+    """VMEM working set of one digit-major grid step, in bytes: blocks of
+    S sublanes, n_out out-groups (or, without an out-map, lane groups) and
+    n_pg pass groups."""
+    plane = S * _W * 4
+    blocks_in = (2 * _D + n_pg * _D) * plane
+    block_out = n_out * _OUT_D * plane if wa else _OUT_D * plane
+    scratch = (wt + n_out * wa) * plane
+    # the F8 table, and with an out-map the borrow constants
+    const = _FOLD8_DM.nbytes + (n_out * plane if wa else 0)
     return {
         "block_bytes": blocks_in + block_out,
         "const_bytes": const,
@@ -895,24 +967,32 @@ def _rows_of(shape) -> int:
     return n
 
 
-def _dm_planes(x, n_lanes: int, rows_p: int):
-    """u64 limbs [..., n_lanes, 25] -> f32 digit planes [n_lanes, 51,
-    rows_p / 128, 128]: digit d of row 128 s + j at [.., d, s, j]."""
+def _dm_planes(x, n_lanes: int, rows_p: int, G: int):
+    """u64 limbs [..., n_lanes, 25] -> f32 digit planes [lane groups, 51,
+    G rows_p / 128, 128]: digit d of row 128 s + j of lane l at [l // G, d,
+    (l % G) rows_p / 128 + s, j], lanes padded with zeros to whole groups
+    (G > 1 only where rows_p / 128 sublanes are one block)."""
     d = fq._to_digits_f32(x.astype(jnp.uint64)).reshape(-1, n_lanes, _D)
-    d = jnp.transpose(d, (1, 2, 0))
-    d = jnp.pad(d, ((0, 0), (0, 0), (0, rows_p - d.shape[2])))
-    return d.reshape(n_lanes, _D, rows_p // _W, _W)
+    n_g = -(-n_lanes // G)
+    d = jnp.pad(d, ((0, 0), (0, n_g * G - n_lanes), (0, 0)))
+    d = jnp.transpose(d.reshape(-1, n_g, G, _D), (1, 3, 2, 0))
+    d = jnp.pad(d, ((0, 0), (0, 0), (0, 0), (0, rows_p - d.shape[3])))
+    return d.reshape(n_g, _D, G * rows_p // _W, _W)
 
 
-# Calls of at least this many rows run the digit-major kernel, the others
-# the lane kernel: the crossover measured on a TPU v5e (PERF.md, the sweep
-# of both layouts by rows and lanes).
+# Calls of at least DIGIT_MAJOR_MIN_ROWS rows run a digit-major kernel, the
+# others the lane kernel: the crossover measured on a TPU v5e (PERF.md, the
+# sweep of both layouts by rows and lanes). Under FULL_BLOCK_ROWS a lane's
+# rows fill less than a block's 8 sublanes, so the block packs lanes.
 DIGIT_MAJOR_MIN_ROWS = 64
+FULL_BLOCK_ROWS = _DM_S * _W
 
 
 def layout_for(rows: int) -> str:
     """The kernel layout a call of ``rows`` rows runs in."""
-    return "digit_major" if rows >= DIGIT_MAJOR_MIN_ROWS else "lane"
+    if rows < DIGIT_MAJOR_MIN_ROWS:
+        return "lane"
+    return "packed" if rows < FULL_BLOCK_ROWS else "digit_major"
 
 
 def _run_fused(A, B, pre_ops, out_key, post_ops, Ain=None):
@@ -923,26 +1003,29 @@ def _run_fused(A, B, pre_ops, out_key, post_ops, Ain=None):
     layout = layout_for(rows)
     PALLAS_KERNELS_TOTAL.inc(layout=layout)
     n_pass = 0 if Ain is None else Ain.shape[1]
+    pre_ops, post_ops = tuple(pre_ops), tuple(post_ops)
     if layout == "lane":
         tile = _row_tile(rows, L)
         rows_p = -(-rows // tile) * tile
         _record_vmem(tile, L, rows_p, out_key)
         run = _build_call(
-            rows_p, tile, L, tuple(pre_ops), out_key, tuple(post_ops),
-            _interpret(),
+            rows_p, tile, L, pre_ops, out_key, post_ops, _interpret()
         )
         ain = None if Ain is None else _planes(Ain, n_pass, rows_p)
         out = run(_planes(A, L, rows_p), _planes(B, L, rows_p), ain)
         return _limbs_of(jnp.moveaxis(out[:, :rows, :_OUT_D], 0, 1))
     sub, rows_p = _dm_rows(rows)
-    _record_dm_vmem(sub, L, rows_p, tuple(pre_ops), out_key, tuple(post_ops))
+    R = L if out_key is None else _OUT_TABLE[out_key][0]
+    G = _dm_group(layout, sub, L, R)
+    _record_dm_vmem(layout, sub, G, L, rows_p, pre_ops, out_key, post_ops)
     run = _build_dm_call(
-        rows_p, sub, L, tuple(pre_ops), out_key, tuple(post_ops), _interpret()
+        layout, rows_p, sub, G, L, pre_ops, out_key, post_ops, _interpret()
     )
-    ain = None if Ain is None else _dm_planes(Ain, n_pass, rows_p)
-    out = run(_dm_planes(A, L, rows_p), _dm_planes(B, L, rows_p), ain)
-    out = out.reshape(out.shape[0], _OUT_D, rows_p)[:, :, :rows]
-    return _limbs_of(jnp.transpose(out, (2, 0, 1)))
+    ain = None if Ain is None else _dm_planes(Ain, n_pass, rows_p, G)
+    out = run(_dm_planes(A, L, rows_p, G), _dm_planes(B, L, rows_p, G), ain)
+    out = out.reshape(out.shape[0], _OUT_D, G, rows_p)[..., :rows]
+    out = jnp.transpose(out, (3, 0, 2, 1)).reshape(rows, -1, _OUT_D)
+    return _limbs_of(out[:, :R])
 
 
 def fused_mul(a, b, lazy: bool = False):
@@ -1061,6 +1144,7 @@ def execute_plan(
     out_value = 0
     for r, lc in enumerate(out_rows):
         row_d = [0] * w
+        neg_d = [0] * w
         row_v = 0
         n_cover = 0
         for idx, c in sorted(lc.d.items()):
@@ -1071,6 +1155,7 @@ def execute_plan(
                 row_v += c * pval
             else:
                 mneg[r, idx] = -c
+                neg_d = [x - c * y for x, y in zip(neg_d, pdig)]
                 n_cover += (-c) * max(pdig)
         if n_cover:
             subc = _dsubc_wide(w, n_cover)
@@ -1080,6 +1165,14 @@ def execute_plan(
         assert fq._cert(
             "pallas_lincomb_f32_exact", max(row_d), _F32_CAP, note=kname
         ), f"{kname}: pallas out-row exceeds f32 exactness"
+        if layout != "lane":
+            # the digit-major kernels add the signed terms in their own
+            # order (by lane group and sublane rotation): any partial sum
+            # lies in [-(negative terms), constant + positive terms]
+            assert fq._cert(
+                "pallas_out_terms_f32_exact", max(row_d + neg_d), _F32_CAP,
+                note=kname,
+            ), f"{kname}: a partial out-row sum exceeds f32 exactness"
         out_digits = [max(x, y) for x, y in zip(out_digits, row_d)]
         out_value = max(out_value, row_v)
 

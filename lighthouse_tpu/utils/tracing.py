@@ -53,9 +53,9 @@ resolved in this process: ``hit`` (loaded, never traced), ``miss`` (traced
 and stored) or ``invalid`` (a bad entry, deleted, traced and stored again).
 
 ``bls_pallas_kernels_total{layout}`` counts the fused Pallas kernel calls
-traced in this process by the block layout their shape chose: ``lane`` or
-``digit_major`` (``ops/bls/pallas_kernels.py``). It counts at trace time, so
-a stage loaded from the store adds nothing.
+traced in this process by the block layout their shape chose: ``lane``,
+``packed`` or ``digit_major`` (``ops/bls/pallas_kernels.py``). It counts at
+trace time, so a stage loaded from the store adds nothing.
 """
 
 from __future__ import annotations
@@ -93,8 +93,8 @@ STAGE_STORE_TOTAL = REGISTRY.counter(
 
 PALLAS_KERNELS_TOTAL = REGISTRY.counter(
     "bls_pallas_kernels_total",
-    "Fused Pallas kernel calls traced, by block layout (lane / digit_major; "
-    "ops/bls/pallas_kernels.py)",
+    "Fused Pallas kernel calls traced, by block layout "
+    "(lane / packed / digit_major; ops/bls/pallas_kernels.py)",
     label_names=("layout",),
 )
 

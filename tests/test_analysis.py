@@ -129,6 +129,8 @@ class TestCertifier:
         kinds = {r["kind"] for r in cert["obligations"]}
         assert "pallas_conv_digit_f32_exact" in kinds
         assert "pallas_out_bound_top_sound" in kinds
+        # pallas.execute_fq12_mul_packed runs the packed layout at batch 1
+        assert "pallas_out_terms_f32_exact" in kinds
 
     def test_seeded_mutation_widened_interior_fails(self, monkeypatch):
         """Widening one lazy interior by one squaring (declared CHAIN bound
@@ -985,6 +987,23 @@ class TestMemoryCertifier:
         assert rows and not rows[0]["ok"]
         assert rows[0]["min_tier"] is None
         assert all(m < 0 for m in rows[0]["margin_bytes"].values())
+
+    def test_packed_vmem_tile_rows(self):
+        """The packed fq12 product (128 rows at batch 1) records its VMEM
+        tile under the packed layout, 8 lanes a block, within the declared
+        16 MiB tier; at batch 32 (4,096 rows) the same graph records a
+        full-block digit-major tile."""
+        cert = amem.certify_memory(
+            backends=("pallas",), batches=(1, 32),
+            graphs=["pallas.execute_fq12_mul_packed"],
+        )
+        assert cert["ok"] and cert["n_failed"] == 0
+        vrows = [r for r in cert["rows"] if r["kind"] == "vmem_tile"]
+        by_layout = {r["layout"]: r for r in vrows}
+        assert set(by_layout) == {"packed", "digit_major"}
+        assert by_layout["packed"]["group"] == 8
+        assert by_layout["digit_major"]["group"] == 1
+        assert all(r["est_vmem_bytes"] <= 16 * 2**20 for r in vrows)
 
     def test_mutation_oversized_vmem_tile_fails(self, monkeypatch):
         """Seeded over-budget mutation #3: an undeclared-tier pallas tile

@@ -92,20 +92,22 @@ def test_fused_kernel_compiles(name, one_chip, tpu_pallas):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
-@pytest.mark.parametrize("rows", [64, 16384])
+@pytest.mark.parametrize("rows", [64, 128, 256, 1024, 16384])
 def test_layout_by_rows_compiles(rows, one_chip, tpu_pallas):
     """The G1 complete addition (two plans, negative out-map terms) compiles
-    in the layout its rows choose: the lane kernel at 64 rows, the
-    digit-major one, named ``fused_digit_major``, at the 512-key tree sum's
-    first level."""
+    in the layout its rows choose: under 1,024 rows the packed kernel, named
+    ``fused_dm_packed`` (8 lanes a block at 64-128 rows, 4 at 256), from
+    there the digit-major one, named ``fused_digit_major``, up to the
+    512-key tree sum's first level."""
     from lighthouse_tpu.ops.bls import curve, pallas_kernels as pk
 
     p = _u64(one_chip, rows, 3, 25)
     text = _compile(lambda a, b: curve.point_add(1, a, b), p, p).as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
-    named = [ln for ln in calls if "fused_digit_major" in ln.split("=")[0]]
+    names = {"packed": "fused_dm_packed", "digit_major": "fused_digit_major"}
+    want = names[pk.layout_for(rows)]
     assert len(calls) == 2
-    assert len(named) == (2 if pk.layout_for(rows) == "digit_major" else 0)
+    assert all(want in ln.split("=")[0] for ln in calls), calls
 
 
 def test_u64_lincomb_compiles(one_chip, tpu_pallas):

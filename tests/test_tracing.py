@@ -247,10 +247,14 @@ def test_pallas_kernel_layouts_counted_at_trace_time(monkeypatch):
 
     before = counts()
     small = jax.ShapeDtypeStruct((pk.DIGIT_MAJOR_MIN_ROWS - 1, 25), jnp.uint64)
-    wide = jax.ShapeDtypeStruct((pk.DIGIT_MAJOR_MIN_ROWS, 25), jnp.uint64)
+    mid = jax.ShapeDtypeStruct((pk.DIGIT_MAJOR_MIN_ROWS, 25), jnp.uint64)
+    wide = jax.ShapeDtypeStruct((pk.FULL_BLOCK_ROWS, 25), jnp.uint64)
     jax.eval_shape(lambda a, b: fq.mont_mul(a, b), small, small)
     jax.eval_shape(lambda a, b: (fq.mont_mul(a, b), fq.mont_mul(b, a)), wide, wide)
+    jax.eval_shape(lambda a, b: [fq.mont_mul(a, b) for _ in range(3)], mid, mid)
     after = counts()
     assert after.get("lane", 0) - before.get("lane", 0) == 1
     assert after.get("digit_major", 0) - before.get("digit_major", 0) == 2
+    assert after.get("packed", 0) - before.get("packed", 0) == 3
+    assert 'bls_pallas_kernels_total{layout="packed"}' in REGISTRY.render()
     assert 'bls_pallas_kernels_total{layout="digit_major"}' in REGISTRY.render()
